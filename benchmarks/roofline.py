@@ -3,9 +3,12 @@
 Reads the dry-run artifacts (JSON + gzipped optimized HLO) and derives the
 three roofline terms per chip per step:
 
-  compute    = HLO_FLOPs  / PEAK_FLOPS          (197 TFLOP/s bf16, v5e)
-  memory     = HLO_bytes  / HBM_BW              (819 GB/s)
-  collective = coll_bytes / ICI_BW              (~50 GB/s/link)
+  compute    = HLO_FLOPs  / peak FLOP/s
+  memory     = HLO_bytes  / peak HBM bytes/s
+  collective = coll_bytes / peak ICI bytes/s per link
+
+with the peaks of the chip the numbers are for (``PEAKS``, keyed by
+``jax.Device.device_kind``; the dry-run describes a TPU v5e pod).
 
 HLO_FLOPs/bytes come from benchmarks.hlo_analysis (trip-count aware — XLA's
 cost_analysis counts scan bodies once); the compiled module is already
@@ -26,9 +29,9 @@ a representative
 queue shape, costed with XLA's compiled ``cost_analysis()``, and executed
 once under a ``jax.profiler.TraceAnnotation`` span matching the
 ``jax.named_scope`` in ``kernels/ops.py`` — so a Perfetto capture of any
-run shows the same ``kernel:*`` names this table prices.  CI's
-bench-smoke job writes the result as ``roofline.json`` next to the race
-artifacts.
+run shows the same ``kernel:*`` names this table prices.  It prices
+against the peaks of the device it runs on, and fails on a device kind
+``PEAKS`` does not list (a CPU among them).
 
 Usage: PYTHONPATH=src:. python -m benchmarks.roofline [--json out.json]
        PYTHONPATH=src:. python -m benchmarks.roofline --kernels --json roofline.json
@@ -44,9 +47,14 @@ from typing import Dict, List, Optional
 
 from .hlo_analysis import analyse_file
 
-PEAK_FLOPS = 197e12      # bf16 / chip (TPU v5e)
-HBM_BW = 819e9           # bytes/s / chip
-ICI_BW = 50e9            # bytes/s / link
+#: per-chip peaks by ``jax.Device.device_kind``.  TPU v5e (reported as
+#: "TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+#: bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect over 4 links
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+#: the chip the dry-run's production meshes describe
+DRYRUN_KIND = "TPU v5 lite"
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
 
 SHAPE_TOKENS = {
@@ -65,7 +73,18 @@ def model_flops(rec: Dict) -> float:
     return 2.0 * n * tokens
 
 
-def decode_ideal_seconds(rec: Dict) -> Optional[float]:
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Roofline peaks of one chip of ``device_kind``; a kind the table
+    does not list is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r}; "
+            f"known kinds: {sorted(PEAKS)}") from None
+
+
+def decode_ideal_seconds(rec: Dict, hbm_bw: float) -> Optional[float]:
     """Bandwidth roofline for decode: one step must read the (TP-sharded)
     active params once per chip plus this chip's share of the KV/state
     cache — that HBM traffic, not FLOPs, is the decode roofline."""
@@ -90,7 +109,7 @@ def decode_ideal_seconds(rec: Dict) -> Optional[float]:
         tp = 16
         param_bytes_per_chip = 2.0 * rec["active_param_count"] / tp
         cache_per_chip = cache_bytes / rec["chips"]
-        return (param_bytes_per_chip + cache_per_chip) / HBM_BW
+        return (param_bytes_per_chip + cache_per_chip) / hbm_bw
     except Exception:
         return None
 
@@ -98,15 +117,16 @@ def decode_ideal_seconds(rec: Dict) -> Optional[float]:
 def analyse_cell(rec: Dict, hlo_path: str) -> Optional[Dict]:
     if rec["status"] != "ok" or not os.path.exists(hlo_path):
         return None
+    pk = peaks(DRYRUN_KIND)
     tot = analyse_file(hlo_path)
     chips = rec["chips"]
-    compute = tot["flops"] / PEAK_FLOPS
-    memory = tot["bytes"] / HBM_BW
-    coll = tot["collective_total"] / ICI_BW
+    compute = tot["flops"] / pk["flops"]
+    memory = tot["bytes"] / pk["hbm_bw"]
+    coll = tot["collective_total"] / pk["ici_bw"]
     bound = max(compute, memory, coll, 1e-12)
     mf = model_flops(rec)
-    ideal = mf / chips / PEAK_FLOPS
-    d_ideal = decode_ideal_seconds(rec)
+    ideal = mf / chips / pk["flops"]
+    d_ideal = decode_ideal_seconds(rec, pk["hbm_bw"])
     if d_ideal is not None:
         ideal = max(ideal, d_ideal)
     dominant = (
@@ -132,15 +152,6 @@ def analyse_cell(rec: Dict, hlo_path: str) -> Optional[Dict]:
     }
 
 
-def _cost(compiled) -> Dict:
-    """Normalise ``compiled.cost_analysis()`` (dict on new jax, list of
-    one dict on older releases) to a plain dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
-
-
 def kernel_rows(n_pages: int = 4096) -> List[Dict]:
     """Roofline rows for the substrate's own ops at a representative
     shape (``n_pages`` ~ the batched sim's page-table width)."""
@@ -149,6 +160,8 @@ def kernel_rows(n_pages: int = 4096) -> List[Dict]:
 
     from repro.kernels import ops
 
+    dev = jax.devices()[0]
+    pk = peaks(dev.device_kind)
     key_f = (jnp.arange(n_pages, dtype=jnp.float32) * 37.0) % 1009.0
     key_i = (jnp.arange(n_pages, dtype=jnp.int32) * 37) % 1009
     sizes = jnp.full((n_pages,), 512.0 * 1024.0, jnp.float32)
@@ -166,11 +179,11 @@ def kernel_rows(n_pages: int = 4096) -> List[Dict]:
     for name, fn, fnargs in cases:
         jfn = jax.jit(fn)
         compiled = jfn.lower(*fnargs).compile()
-        c = _cost(compiled)
+        c = compiled.cost_analysis()
         flops = float(c.get("flops", 0.0))
         nbytes = float(c.get("bytes accessed", 0.0))
-        compute = flops / PEAK_FLOPS
-        memory = nbytes / HBM_BW
+        compute = flops / pk["flops"]
+        memory = nbytes / pk["hbm_bw"]
         # exercise the span: the TraceAnnotation nests around the op's own
         # jax.named_scope, so profiler captures carry both labels
         with jax.profiler.TraceAnnotation(f"kernel:{name}"):
@@ -178,7 +191,8 @@ def kernel_rows(n_pages: int = 4096) -> List[Dict]:
         rows.append({
             "kernel": name,
             "backend": ops.get_backend(),
-            "platform": jax.default_backend(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
             "n_pages": n_pages,
             "flops": flops,
             "bytes": nbytes,

@@ -72,6 +72,8 @@ def main() -> None:
         # boxes — short lanes free their cores to the long ones)
         from benchmarks.tpch import setup_lane_devices
         setup_lane_devices()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import microbench, tpch, sharing, serving_bench, data_bench
 
@@ -180,22 +182,19 @@ def main() -> None:
 
     if not args.skip_roofline:
         print("# === roofline (from dry-run artifacts) ===", file=sys.stderr)
-        try:
-            from benchmarks import roofline
+        from benchmarks import roofline
 
-            rrows = roofline.run()
-            with open(os.path.join(RESULTS_DIR, "roofline.json"), "w") as f:
-                json.dump(rrows, f, indent=2)
-            for r in rrows:
-                if r.get("dominant") == "SKIPPED":
-                    continue
-                _csv(
-                    f"roofline_{r['arch']}_{r['shape']}",
-                    r["bound_s"] * 1e6,
-                    f"{r['roofline_frac']:.4f}",
-                )
-        except Exception as e:  # noqa: BLE001
-            print(f"# roofline unavailable: {e}", file=sys.stderr)
+        rrows = roofline.run()
+        with open(os.path.join(RESULTS_DIR, "roofline.json"), "w") as f:
+            json.dump(rrows, f, indent=2)
+        for r in rrows:
+            if r.get("dominant") == "SKIPPED":
+                continue
+            _csv(
+                f"roofline_{r['arch']}_{r['shape']}",
+                r["bound_s"] * 1e6,
+                f"{r['roofline_frac']:.4f}",
+            )
 
 
 if __name__ == "__main__":

@@ -543,8 +543,10 @@ def setup_lane_devices(n: Optional[int] = None) -> None:
     """Expose several XLA host devices for lane-sharded CPU execution.
 
     Must run before JAX initialises (the flag is read once at backend
-    creation); a no-op when the flag is already set, when running on a
-    real accelerator platform, or when JAX is already imported.
+    creation); a no-op when the flag is already set or when JAX is already
+    imported.  The flag shapes only XLA's CPU host platform: on an
+    accelerator it is still set but changes nothing there, and the mesh
+    spans the accelerator's own devices.
 
     Deliberately exposes MORE devices than cores (8 by default): one
     lane per device lets short lanes finish and hand their cores to the
@@ -593,8 +595,11 @@ def main() -> None:
                          "experiments/results/scaling_curve.json")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
-    if args.scaling:
+    if args.scaling or (args.backend == "array" and args.mesh == "auto"):
         setup_lane_devices()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if args.scaling:
         scale = args.scale if args.scale is not None else SMOKE_SCALE
         curve = scaling_curve(scale=scale)
         out = args.out or "experiments/results/scaling_curve.json"
@@ -603,8 +608,6 @@ def main() -> None:
             json.dump(curve, f, indent=2)
         print(f"  tpch scaling curve -> {out}")
         return
-    if args.backend == "array" and args.mesh == "auto":
-        setup_lane_devices()
     smoke_scale = SMOKE_SCALE if args.backend == "array" \
         else EVENT_SMOKE_SCALE
     scale = args.scale if args.scale is not None else (

@@ -2,7 +2,7 @@
 KV pages.
 
 PagedTinyLM computes every decode step with ``kernels.paged_attention``
-(interpret mode on CPU, Mosaic on TPU) reading K/V through the page tables
+(Mosaic on TPU, the jnp oracle elsewhere) reading K/V through the page tables
 that the ServingEngine + PagePool manage: prefix sharing, PBM preemption,
 host spill — the kernel never sees a contiguous cache.
 
@@ -15,13 +15,11 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
-from repro.kernels import ops
 from repro.serving import PagePool, Request, ServingEngine
 from repro.serving.model import PagedTinyLM, TinyConfig
 
 
 def main():
-    ops.set_backend("interpret")  # execute the Pallas kernel body on CPU
     cfg = TinyConfig(n_pages=96, page_size=16)
     lm = PagedTinyLM(cfg, seed=0)
     pool = PagePool(n_pages=cfg.n_pages, page_size=cfg.page_size,

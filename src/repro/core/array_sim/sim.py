@@ -1624,15 +1624,13 @@ def make_runner(
         return run(cfg)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
-
         # configs shard over the lane axis only; per-page state is
         # replicated across the page axis (each page shard scans its own
         # pool slice inside the kernels — kops page_axis dispatch above)
         pspec = jax.sharding.PartitionSpec(mesh.axis_names[0])
-        runner = jax.jit(shard_map(
+        runner = jax.jit(jax.shard_map(
             jax.vmap(counted_run), mesh=mesh,
-            in_specs=(pspec,), out_specs=pspec, check_rep=False,
+            in_specs=(pspec,), out_specs=pspec, check_vma=False,
         ))
     elif sanitize:
         from jax.experimental import checkify

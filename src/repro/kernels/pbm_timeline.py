@@ -34,6 +34,9 @@ Design notes
   bytes) run as an extra leading phase axis: TPU grids are sequential,
   so phase 0 fully populates the (1, P_pad) scratch before phase 1
   reads it.
+* A tile whose row or column page block holds no candidate (queued or
+  evictable page) adds exactly zero, so the kernels skip it under
+  ``pl.when`` on a per-block occupancy row passed in SMEM.
 
 Semantics are defined by the oracles in ``repro.kernels.ref``
 (``batched_evict_ref`` / ``fifo_grant_ref`` / ``wake_solve_ref``);
@@ -69,8 +72,39 @@ def _pad_row(row: jax.Array, p_pad: int, fill) -> jax.Array:
     return jnp.pad(row, ((0, 0), (0, pad)), constant_values=fill)
 
 
-def _kernel(fscal_ref, key_i_ref, key_j_ref, sizes_j_ref, ev_i_ref, ev_j_ref,
-            evict_out_ref, freed_acc_ref, rank_acc_ref,
+def _iota_f32(shape, dim: int) -> jax.Array:
+    # Mosaic's iota yields integers only: build it as i32, then cast
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
+
+
+def _prefix_dot(mask: jax.Array, col: jax.Array) -> jax.Array:
+    # 0/1 mask contracted against f32 page bytes on the MXU.  HIGHEST keeps
+    # every byte of the f32 operand: at the default precision the chip
+    # rounds it to bf16, which cannot hold every page size exactly
+    return jnp.dot(mask.astype(jnp.float32), col,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _order_before(key_p, key_q, i, j, block: int) -> jax.Array:
+    """One (block, block) tile of the priority order: does column page q
+    come before row page p?  Descending key, ties by ascending global
+    page index."""
+    gq = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    gp = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    return (key_q > key_p) | ((key_q == key_p) & (gq < gp))
+
+
+def _occupied(mask_row: jax.Array, n_b: int) -> jax.Array:
+    """(1, n_b) i32: does page block b hold any page of ``mask_row``?
+    Tiles whose row or column block holds none add nothing to the prefix
+    sums, so the kernels skip them."""
+    return jnp.any(mask_row.reshape(n_b, _BLOCK), axis=1).astype(
+        jnp.int32).reshape(1, n_b)
+
+
+def _kernel(fscal_ref, occ_ref, key_i_ref, key_j_ref, sizes_j_ref, ev_i_ref,
+            ev_j_ref, evict_out_ref, freed_acc_ref, rank_acc_ref,
             *, vmax: int, block: int, n_j: int, int_key: bool = False):
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -82,22 +116,23 @@ def _kernel(fscal_ref, key_i_ref, key_j_ref, sizes_j_ref, ev_i_ref, ev_j_ref,
         rank_acc_ref[...] = jnp.zeros_like(rank_acc_ref)
 
     ev_i = ev_i_ref[:]                # (1, block) f32 0/1 — the row pages p
-    ev_j = ev_j_ref[:]                # (1, block): candidate predecessors q
-    neg = NEG_I32 if int_key else NEG
-    key_p = jnp.where(ev_i > 0, key_i_ref[:], neg).reshape(block, 1)
-    key_q = jnp.where(ev_j > 0, key_j_ref[:], neg)
 
-    # ---- one (block, block) tile of the priority-order prefix matrix -----
-    gq = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    gp = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    before = (key_q > key_p) | ((key_q == key_p) & (gq < gp))
-    sz = (sizes_j_ref[:] * ev_j).reshape(block, 1)
-    freed_acc_ref[...] = freed_acc_ref[...] + jnp.dot(
-        before.astype(jnp.float32), sz, preferred_element_type=jnp.float32,
-    ).reshape(1, block)                # bytes freed before page p (partial)
-    rank_acc_ref[...] = rank_acc_ref[...] + jnp.sum(
-        before, axis=1, dtype=jnp.float32,
-    ).reshape(1, block)
+    # nothing is taken while nothing needs freeing, and a block without
+    # evictable pages holds only sentinel keys, which precede no
+    # evictable page: such tiles leave the accumulators as they are
+    @pl.when((need_free > 0) & (occ_ref[0, i] > 0) & (occ_ref[0, j] > 0))
+    def _acc():
+        ev_j = ev_j_ref[:]            # (1, block): candidate predecessors q
+        neg = NEG_I32 if int_key else NEG
+        key_p = jnp.where(ev_i > 0, key_i_ref[:], neg).reshape(block, 1)
+        key_q = jnp.where(ev_j > 0, key_j_ref[:], neg)
+        before = _order_before(key_p, key_q, i, j, block)
+        sz = (sizes_j_ref[:] * ev_j).reshape(block, 1)
+        freed_acc_ref[...] = freed_acc_ref[...] + _prefix_dot(
+            before, sz).reshape(1, block)    # bytes freed before page p
+        rank_acc_ref[...] = rank_acc_ref[...] + jnp.sum(
+            before, axis=1, dtype=jnp.float32,
+        ).reshape(1, block)
 
     @pl.when(j == n_j - 1)
     def _commit():
@@ -112,7 +147,7 @@ def _kernel(fscal_ref, key_i_ref, key_j_ref, sizes_j_ref, ev_i_ref, ev_j_ref,
         evict_out_ref[...] = take.astype(jnp.float32)
 
 
-def _grant_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
+def _grant_kernel(iscal_ref, fscal_ref, occ_ref, key_i_ref, key_j_ref,
                   sizes_i_ref, sizes_j_ref, grant_out_ref,
                   fits_ref, bytes_acc_ref, rank_acc_ref, blk_acc_ref,
                   *, vmax: int, block: int, n_j: int):
@@ -122,15 +157,16 @@ def _grant_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
     pops = iscal_ref[0, 0]
     budget = fscal_ref[0, 0]
 
-    key_p = key_i_ref[:].reshape(block, 1)
-    key_q = key_j_ref[:]              # (1, block) i32 — the FIFO keys use up
-    wanted_p = key_i_ref[:] >= 0      # to ~30 bits (stamp*32768 + tie), so
-    wanted_q = key_q >= 0             # an f32 cast would round away the
-                                      # tie bits beyond 2^24
-    # service order: descending key, ties by ascending global index
-    gq = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    gp = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    before = ((key_q > key_p) | ((key_q == key_p) & (gq < gp))) & wanted_q
+    wanted_p = key_i_ref[:] >= 0      # (1, block) i32 keys — the FIFO keys
+    wanted_q = key_j_ref[:] >= 0      # use up to ~30 bits (stamp*32768 +
+                                      # tie), so an f32 cast would round
+                                      # away the tie bits beyond 2^24
+    # a tile whose row or column block holds no queued page changes nothing
+    busy = (occ_ref[0, i] > 0) & (occ_ref[0, j] > 0)
+
+    def before():
+        return _order_before(key_i_ref[:].reshape(block, 1), key_j_ref[:],
+                             i, j, block) & wanted_q
 
     # ---- phase 0: budget/pops feasibility per page ("fits") --------------
     @pl.when((ph == 0) & (j == 0))
@@ -138,15 +174,14 @@ def _grant_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
         bytes_acc_ref[...] = jnp.zeros_like(bytes_acc_ref)
         rank_acc_ref[...] = jnp.zeros_like(rank_acc_ref)
 
-    @pl.when(ph == 0)
+    @pl.when((ph == 0) & busy)
     def _acc_fits():
+        b = before()
         sz = (sizes_j_ref[:] * wanted_q).reshape(block, 1)
-        bytes_acc_ref[...] = bytes_acc_ref[...] + jnp.dot(
-            before.astype(jnp.float32), sz,
-            preferred_element_type=jnp.float32,
-        ).reshape(1, block)
+        bytes_acc_ref[...] = bytes_acc_ref[...] + _prefix_dot(
+            b, sz).reshape(1, block)
         rank_acc_ref[...] = rank_acc_ref[...] + jnp.sum(
-            before, axis=1, dtype=jnp.float32,
+            b, axis=1, dtype=jnp.float32,
         ).reshape(1, block)
 
     @pl.when((ph == 0) & (j == n_j - 1))
@@ -166,14 +201,12 @@ def _grant_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
     def _init_blk():
         blk_acc_ref[...] = jnp.zeros_like(blk_acc_ref)
 
-    @pl.when(ph == 1)
+    @pl.when((ph == 1) & busy)
     def _acc_blk():
         fits_j = fits_ref[0, pl.ds(j * block, block)].reshape(1, block)
         nonfit = (wanted_q & (fits_j == 0)).astype(jnp.float32)
-        blk_acc_ref[...] = blk_acc_ref[...] + jnp.dot(
-            before.astype(jnp.float32), nonfit.reshape(block, 1),
-            preferred_element_type=jnp.float32,
-        ).reshape(1, block)
+        blk_acc_ref[...] = blk_acc_ref[...] + _prefix_dot(
+            before(), nonfit.reshape(block, 1)).reshape(1, block)
 
     @pl.when((ph == 1) & (j == n_j - 1))
     def _commit():
@@ -182,7 +215,7 @@ def _grant_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
             ((fits_i > 0) & (blk_acc_ref[...] == 0)).astype(jnp.float32)
 
 
-def _wake_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
+def _wake_kernel(iscal_ref, fscal_ref, occ_ref, key_i_ref, key_j_ref,
                  sizes_i_ref, sizes_j_ref, wake_out_ref,
                  csum_ref, rank_ref, bytes_acc_ref, rank_acc_ref,
                  cnt_ref, nk_ref,
@@ -194,13 +227,8 @@ def _wake_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
     credit0 = fscal_ref[0, 0]
     inc = fscal_ref[0, 1]
 
-    key_p = key_i_ref[:].reshape(block, 1)
-    key_q = key_j_ref[:]
     wanted_p = key_i_ref[:] >= 0
-    wanted_q = key_q >= 0
-    gq = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    gp = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    before = ((key_q > key_p) | ((key_q == key_p) & (gq < gp))) & wanted_q
+    wanted_q = key_j_ref[:] >= 0
 
     # ---- phase 0: service rank + prefix-inclusive queue bytes per page ---
     @pl.when((ph == 0) & (j == 0))
@@ -208,13 +236,14 @@ def _wake_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
         bytes_acc_ref[...] = jnp.zeros_like(bytes_acc_ref)
         rank_acc_ref[...] = jnp.zeros_like(rank_acc_ref)
 
-    @pl.when(ph == 0)
+    # tiles whose row or column block holds no queued page add nothing
+    @pl.when((ph == 0) & (occ_ref[0, i] > 0) & (occ_ref[0, j] > 0))
     def _acc_prefix():
+        before = _order_before(key_i_ref[:].reshape(block, 1), key_j_ref[:],
+                               i, j, block) & wanted_q
         sz = (sizes_j_ref[:] * wanted_q).reshape(block, 1)
-        bytes_acc_ref[...] = bytes_acc_ref[...] + jnp.dot(
-            before.astype(jnp.float32), sz,
-            preferred_element_type=jnp.float32,
-        ).reshape(1, block)
+        bytes_acc_ref[...] = bytes_acc_ref[...] + _prefix_dot(
+            before, sz).reshape(1, block)
         rank_acc_ref[...] = rank_acc_ref[...] + jnp.sum(
             before, axis=1, dtype=jnp.float32,
         ).reshape(1, block)
@@ -231,10 +260,10 @@ def _wake_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
     def _init_cnt():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    @pl.when((ph == 1) & (i == 0))
+    @pl.when((ph == 1) & (i == 0) & (occ_ref[0, j] > 0))
     def _acc_cnt():
         cs = csum_ref[0, pl.ds(j * block, block)].reshape(1, block)
-        ks = 1.0 + jax.lax.broadcasted_iota(jnp.float32, (h_cap, block), 0)
+        ks = _iota_f32((h_cap, block), 0) + 1.0
         ok = wanted_q & (cs <= credit0 + ks * inc)
         cnt_ref[...] = cnt_ref[...] + jnp.sum(
             ok, axis=1, dtype=jnp.float32,
@@ -247,12 +276,12 @@ def _wake_kernel(iscal_ref, fscal_ref, key_i_ref, key_j_ref,
     @pl.when((ph == 2) & (i == 0) & (j == 0))
     def _solve_ramp():
         popf = jnp.maximum(pops, 0).astype(jnp.float32)
-        kk = 1.0 + jax.lax.broadcasted_iota(jnp.float32, (h_cap, h_cap), 0)
-        jj = 1.0 + jax.lax.broadcasted_iota(jnp.float32, (h_cap, h_cap), 1)
+        kk = _iota_f32((h_cap, h_cap), 0) + 1.0
+        jj = _iota_f32((h_cap, h_cap), 1) + 1.0
         gap = kk - jj
         ramp = jnp.where(
             gap >= 0, cnt_ref[...].reshape(1, h_cap) + gap * popf, jnp.inf)
-        ks = 1.0 + jax.lax.broadcasted_iota(jnp.float32, (h_cap, 1), 0)
+        ks = _iota_f32((h_cap, 1), 0) + 1.0
         nk_ref[...] = jnp.minimum(
             jnp.min(ramp, axis=1).reshape(h_cap, 1), ks * popf)
 
@@ -287,6 +316,7 @@ def fifo_grant_kernel(
     sz_row = _pad_row(sizes.reshape(1, P).astype(jnp.float32), p_pad, 0.0)
     iscal = jnp.asarray(pops, jnp.int32).reshape(1, 1)
     fscal = jnp.asarray(budget, jnp.float32).reshape(1, 1)
+    occ = _occupied(key_row >= 0, n_b)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     row_i = pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
                          memory_space=pltpu.VMEM)
@@ -297,7 +327,7 @@ def fifo_grant_kernel(
                           n_j=n_b),
         grid=(2, n_b, n_b),
         out_shape=jax.ShapeDtypeStruct((1, p_pad), jnp.float32),
-        in_specs=[smem, smem, row_i, row_j, row_i, row_j],
+        in_specs=[smem, smem, smem, row_i, row_j, row_i, row_j],
         out_specs=pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -307,7 +337,7 @@ def fifo_grant_kernel(
             pltpu.VMEM((1, _BLOCK), jnp.float32),  # blocked accumulator
         ],
         interpret=interpret,
-    )(iscal, fscal, key_row, key_row, sz_row, sz_row)
+    )(iscal, fscal, occ, key_row, key_row, sz_row, sz_row)
     mask = grant[0, :P] > 0
     granted = jnp.where(mask, sizes, 0.0)
     return mask, jnp.sum(granted), jnp.sum(mask)
@@ -342,6 +372,7 @@ def batched_evict_kernel(
     ev_row = _pad_row(
         evictable.reshape(1, P).astype(jnp.float32), p_pad, 0.0)
     fscal = jnp.asarray(need_free, jnp.float32).reshape(1, 1)
+    occ = _occupied(ev_row > 0, n_b)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     row_i = pl.BlockSpec((1, _BLOCK), lambda i, j: (0, i),
                          memory_space=pltpu.VMEM)
@@ -352,7 +383,7 @@ def batched_evict_kernel(
                           n_j=n_b, int_key=int_key),
         grid=(n_b, n_b),
         out_shape=jax.ShapeDtypeStruct((1, p_pad), jnp.float32),
-        in_specs=[smem, row_i, row_j, row_j, row_i, row_j],
+        in_specs=[smem, smem, row_i, row_j, row_j, row_i, row_j],
         out_specs=pl.BlockSpec((1, _BLOCK), lambda i, j: (0, i),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -360,7 +391,7 @@ def batched_evict_kernel(
             pltpu.VMEM((1, _BLOCK), jnp.float32),  # rank accumulator
         ],
         interpret=interpret,
-    )(fscal, key_row, key_row, sz_row, ev_row, ev_row)
+    )(fscal, occ, key_row, key_row, sz_row, ev_row, ev_row)
     return evict[0, :P] > 0
 
 
@@ -393,6 +424,7 @@ def wake_solve_kernel(
     fscal = jnp.stack([
         jnp.asarray(credit0, jnp.float32), jnp.asarray(inc, jnp.float32),
     ]).reshape(1, 2)
+    occ = _occupied(key_row >= 0, n_b)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     row_i = pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
                          memory_space=pltpu.VMEM)
@@ -402,7 +434,7 @@ def wake_solve_kernel(
         functools.partial(_wake_kernel, h_cap=h_cap, block=_BLOCK, n_j=n_b),
         grid=(3, n_b, n_b),
         out_shape=jax.ShapeDtypeStruct((1, p_pad), jnp.int32),
-        in_specs=[smem, smem, row_i, row_j, row_i, row_j],
+        in_specs=[smem, smem, smem, row_i, row_j, row_i, row_j],
         out_specs=pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
@@ -414,5 +446,5 @@ def wake_solve_kernel(
             pltpu.VMEM((h_cap, 1), jnp.float32),    # n_k
         ],
         interpret=interpret,
-    )(iscal, fscal, key_row, key_row, sz_row, sz_row)
+    )(iscal, fscal, occ, key_row, key_row, sz_row, sz_row)
     return wake[0, :P]

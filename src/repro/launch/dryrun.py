@@ -186,17 +186,8 @@ def _lower_cell(cfg, shape, mesh):
         return jitted.lower(params_abs, cache_abs, batch_abs)
 
 
-def cost_analysis_dict(compiled) -> Dict[str, Any]:
-    """``compiled.cost_analysis()`` normalised across jaxlib versions
-    (older releases returned ``[dict]`` instead of ``dict``)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    return cost
-
-
 def _analyse(compiled) -> Dict[str, Any]:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     return {
         "flops": cost.get("flops", 0.0),

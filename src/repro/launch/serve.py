@@ -3,8 +3,9 @@
   PYTHONPATH=src python -m repro.launch.serve --requests 16 --policy pbm
 
 Runs the continuous-batching engine over an oversubscribed HBM page pool
-with a shared system prompt; ``--real-model`` decodes through the Pallas
-paged-attention kernel (interpret mode on CPU) instead of the fast stub.
+with a shared system prompt; ``--real-model`` decodes through
+``kernels.ops.paged_attention`` (the Mosaic kernel on a TPU, the jnp
+oracle elsewhere) instead of the fast stub.
 """
 
 from __future__ import annotations
@@ -32,10 +33,8 @@ def main() -> None:
 
     rng = np.random.default_rng(args.seed)
     if args.real_model:
-        from repro.kernels import ops
         from repro.serving.model import PagedTinyLM, TinyConfig
 
-        ops.set_backend("interpret")
         cfg = TinyConfig(n_pages=args.pool_pages, page_size=args.page_size)
         lm = PagedTinyLM(cfg, seed=args.seed)
         step_fn = lm.step_fn

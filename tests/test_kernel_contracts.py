@@ -44,6 +44,7 @@ from repro.kernels import ops, ref  # noqa: E402
 from repro.kernels.pbm_timeline import (  # noqa: E402
     batched_evict_kernel,
     fifo_grant_kernel,
+    wake_solve_kernel,
 )
 
 try:
@@ -475,6 +476,49 @@ def test_fifo_grant_edge_cases():
     _assert_grant_agrees(*_grant_case(rng, 64, zero_budget=True))
     _assert_grant_agrees(*_grant_case(rng, 64, none_wanted=True))
     _assert_grant_agrees(*_grant_case(rng, 1))
+
+
+def _sparse_blocks(rng, P, fill):
+    """A mask that leaves page blocks 1 and 3 of a four-block pool empty:
+    the kernels skip every tile those blocks touch."""
+    mask = rng.random(P) < fill
+    block = np.arange(P) // 512
+    return mask & (block % 2 == 0)
+
+
+@pytest.mark.parametrize("kernel", ["evict_f32", "evict_i32", "grant",
+                                    "wake"])
+def test_kernels_skip_empty_page_blocks_exactly(kernel):
+    """Tiles whose row or column page block holds no candidate are
+    skipped; the results must still equal the oracles exactly, on a
+    ragged four-block pool where blocks 1 and 3 are empty."""
+    rng = np.random.default_rng(11)
+    P = 3 * 512 + 129
+    sizes = jnp.asarray(rng.integers(1, 9, P) * 4096, jnp.float32)
+    if kernel.startswith("evict"):
+        if kernel == "evict_i32":
+            key = jnp.asarray(rng.integers(0, 2**30, P), jnp.int32)
+        else:
+            key = jnp.asarray(rng.integers(-50, 50, P), jnp.float32)
+        ev = jnp.asarray(_sparse_blocks(rng, P, 0.3))
+        for need in (0.0, 40 * 4096.0, 1e9):
+            _assert_evict_agrees(key, sizes, ev, jnp.float32(need), 64)
+        return
+    queued = _sparse_blocks(rng, P, 0.05)
+    key = jnp.asarray(np.where(queued, rng.integers(0, 2**29, P), -1),
+                      jnp.int32)
+    if kernel == "grant":
+        for budget in (0.0, 20 * 4096.0, 1e9):
+            _assert_grant_agrees(key, sizes, jnp.float32(budget),
+                                 jnp.int32(9), 16)
+        return
+    for credit0 in (0.0, 1e5, 1e7):
+        got = wake_solve_kernel(key, sizes, jnp.float32(credit0),
+                                jnp.float32(6e4), jnp.int32(3), h_cap=12,
+                                interpret=True)
+        want = ref.wake_solve_ref(key, sizes, jnp.float32(credit0),
+                                  jnp.float32(6e4), jnp.int32(3), h_cap=12)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 if HAVE_HYPOTHESIS:

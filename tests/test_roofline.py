@@ -9,11 +9,9 @@ from benchmarks.hlo_analysis import HloModule
 
 
 def _totals(fn, *args):
-    from repro.launch.dryrun import cost_analysis_dict
-
     c = jax.jit(fn).lower(*args).compile()
     mod = HloModule(c.as_text())
-    return mod.totals(), cost_analysis_dict(c)
+    return mod.totals(), c.cost_analysis()
 
 
 A = jax.ShapeDtypeStruct((256, 256), jnp.float32)
@@ -63,10 +61,8 @@ def test_collectives_counted_with_trips():
             return s @ a, None
         return jax.lax.scan(body, a, None, length=3)[0]
 
-    from functools import partial
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
     c = jax.jit(fn).lower(jnp.ones((64, 64))).compile()
     mod = HloModule(c.as_text())
     t = mod.totals()
@@ -84,3 +80,14 @@ def test_bytes_nonzero_and_scale_with_trips():
     t1, _ = _totals(f1, A)
     t2, _ = _totals(f2, A)
     assert t2["bytes"] > 2.5 * t1["bytes"]
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_roofline_peaks_refuse_unknown_device_kind(kind):
+    """Peaks are keyed by device kind; an unlisted kind is an error, not
+    a silent v5e default."""
+    from benchmarks.roofline import PEAKS, peaks
+
+    assert peaks("TPU v5 lite") is PEAKS["TPU v5 lite"]
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        peaks(kind)

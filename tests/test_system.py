@@ -116,9 +116,7 @@ def test_smoke_dryrun_lowering_small_mesh(arch):
             in_shardings=(named(p_specs), named(o_specs), named(b_specs)),
         ).lower(params_abs, opt_abs, batch_abs)
         compiled = lowered.compile()
-    from repro.launch.dryrun import cost_analysis_dict
-
-    assert cost_analysis_dict(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
 
 
 def test_dryrun_artifacts_exist_and_pass():

@@ -231,7 +231,6 @@ def test_page_sharded_ops_match_unsharded_on_multi_device_mesh():
 import numpy as np, jax, jax.numpy as jnp
 from functools import partial
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.kernels import ops, ref
 
 mesh = Mesh(np.array(jax.devices()[:4]), ("page",))
@@ -246,19 +245,19 @@ for trial in range(4):
     ev = jnp.asarray(rng.random(Pn) < 0.7)
     fkey = jnp.asarray(rng.random(Pn), jnp.float32)
 
-    g = shard_map(
+    g = jax.shard_map(
         partial(ops.fifo_grant, vmax=16, page_axis="page"),
         mesh=mesh, in_specs=(P(), P(), P(), P()),
-        out_specs=(P(), P(), P()), check_rep=False,
+        out_specs=(P(), P(), P()), check_vma=False,
     )(key, sizes, budget, pops)
     gr = ref.fifo_grant_ref(key, sizes, budget, pops, vmax=16)
     assert (np.asarray(g[0]) == np.asarray(gr[0])).all(), trial
     assert float(g[1]) == float(gr[1]) and int(g[2]) == int(gr[2]), trial
 
-    e = shard_map(
+    e = jax.shard_map(
         partial(ops.batched_evict, vmax=64, page_axis="page"),
         mesh=mesh, in_specs=(P(), P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(fkey, sizes, ev, need)
     er = ref.batched_evict_ref(fkey, sizes, ev, need, vmax=64)
     assert (np.asarray(e) == np.asarray(er)).all(), trial
