@@ -23,18 +23,7 @@ compute), the dominant term, and the roofline fraction
 i.e. model-flops utilisation assuming the step runs at the binding term —
 the number §Perf hillclimbs.
 
-``--kernels`` switches to the substrate's own Pallas ops
-(``fifo_grant`` / ``batched_evict`` / ``wake_solve``): each is lowered at
-a representative
-queue shape, costed with XLA's compiled ``cost_analysis()``, and executed
-once under a ``jax.profiler.TraceAnnotation`` span matching the
-``jax.named_scope`` in ``kernels/ops.py`` — so a Perfetto capture of any
-run shows the same ``kernel:*`` names this table prices.  It prices
-against the peaks of the device it runs on, and fails on a device kind
-``PEAKS`` does not list (a CPU among them).
-
 Usage: PYTHONPATH=src:. python -m benchmarks.roofline [--json out.json]
-       PYTHONPATH=src:. python -m benchmarks.roofline --kernels --json roofline.json
 """
 
 from __future__ import annotations
@@ -152,71 +141,6 @@ def analyse_cell(rec: Dict, hlo_path: str) -> Optional[Dict]:
     }
 
 
-def kernel_rows(n_pages: int = 4096) -> List[Dict]:
-    """Roofline rows for the substrate's own ops at a representative
-    shape (``n_pages`` ~ the batched sim's page-table width)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops
-
-    dev = jax.devices()[0]
-    pk = peaks(dev.device_kind)
-    key_f = (jnp.arange(n_pages, dtype=jnp.float32) * 37.0) % 1009.0
-    key_i = (jnp.arange(n_pages, dtype=jnp.int32) * 37) % 1009
-    sizes = jnp.full((n_pages,), 512.0 * 1024.0, jnp.float32)
-    evictable = (jnp.arange(n_pages) % 3) != 0
-    cases = [
-        ("fifo_grant", ops.fifo_grant,
-         (key_i, sizes, jnp.float32(64 << 20), jnp.int32(16))),
-        ("batched_evict", ops.batched_evict,
-         (key_f, sizes, evictable, jnp.float32(32 << 20))),
-        ("wake_solve", ops.wake_solve,
-         (key_i, sizes, jnp.float32(4 << 20), jnp.float32(1 << 20),
-          jnp.int32(6))),
-    ]
-    rows = []
-    for name, fn, fnargs in cases:
-        jfn = jax.jit(fn)
-        compiled = jfn.lower(*fnargs).compile()
-        c = compiled.cost_analysis()
-        flops = float(c.get("flops", 0.0))
-        nbytes = float(c.get("bytes accessed", 0.0))
-        compute = flops / pk["flops"]
-        memory = nbytes / pk["hbm_bw"]
-        # exercise the span: the TraceAnnotation nests around the op's own
-        # jax.named_scope, so profiler captures carry both labels
-        with jax.profiler.TraceAnnotation(f"kernel:{name}"):
-            jax.block_until_ready(jfn(*fnargs))
-        rows.append({
-            "kernel": name,
-            "backend": ops.get_backend(),
-            "platform": dev.platform,
-            "device_kind": dev.device_kind,
-            "n_pages": n_pages,
-            "flops": flops,
-            "bytes": nbytes,
-            "transcendentals": float(c.get("transcendentals", 0.0)),
-            "compute_s": compute,
-            "memory_s": memory,
-            "dominant": "compute" if compute >= memory else "memory",
-        })
-    return rows
-
-
-def fmt_kernel_table(rows: List[Dict]) -> str:
-    hdr = (f"{'kernel':16s} {'P':>6s} {'flops':>12s} {'bytes':>12s} "
-           f"{'comp_us':>9s} {'mem_us':>9s} {'bound':>8s}")
-    out = [hdr, "-" * len(hdr)]
-    for r in rows:
-        out.append(
-            f"{r['kernel']:16s} {r['n_pages']:6d} {r['flops']:12.3e} "
-            f"{r['bytes']:12.3e} {r['compute_s']*1e6:9.3f} "
-            f"{r['memory_s']*1e6:9.3f} {r['dominant']:>8s}"
-        )
-    return "\n".join(out)
-
-
 def run(dryrun_dir: str = DRYRUN_DIR) -> List[Dict]:
     rows = []
     for jf in sorted(glob.glob(os.path.join(dryrun_dir, "*__pod.json"))):
@@ -256,28 +180,12 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dryrun-dir", default=DRYRUN_DIR)
     ap.add_argument("--json", default=None)
-    ap.add_argument("--kernels", action="store_true",
-                    help="cost the substrate's fifo_grant/batched_evict "
-                         "ops instead of the dry-run artifacts")
-    ap.add_argument("--pages", type=int, default=4096,
-                    help="--kernels queue width")
     args = ap.parse_args()
-    if args.kernels:
-        rows = kernel_rows(args.pages)
-        print(fmt_kernel_table(rows))
-        payload: object = rows
-        try:
-            from repro.obs import manifest as _manifest
-            payload = {"manifest": _manifest.collect(), "kernels": rows}
-        except Exception:
-            pass
-    else:
-        rows = run(args.dryrun_dir)
-        print(fmt_table(rows))
-        payload = rows
+    rows = run(args.dryrun_dir)
+    print(fmt_table(rows))
     if args.json:
         with open(args.json, "w") as f:
-            json.dump(payload, f, indent=2)
+            json.dump(rows, f, indent=2)
 
 
 if __name__ == "__main__":

@@ -79,6 +79,7 @@ import numpy as np
 
 from .. import policy_registry
 from ...obs import counters as obs
+from ...obs import phases
 from . import coop as coop_mod
 from .policies import BIG_CUT, ArrayPolicy, HorizonView, StepCtx
 from .spec import SimSpec
@@ -373,6 +374,12 @@ def make_step(spec: SimSpec, dt: float, time_slice: float,
     from values the step already computes; with it off (the default) the
     carry, the compiled program, and the results are exactly the
     pre-telemetry ones.
+
+    The step's sections run under the named phases of ``repro.obs``
+    (``step:cpu``, ``step:demand``, ``step:io``, ``step:evict``,
+    ``step:obs``, ``step:horizon``; DESIGN.md §8): ``op_name`` metadata
+    on the compiled ops, which a profiler capture attributes device
+    time by, and nothing else.
     """
     from repro.kernels import ops as kops
 
@@ -581,587 +588,591 @@ def make_step(spec: SimSpec, dt: float, time_slice: float,
         ``adv_lim_in`` is the carried advance limit the previous horizon
         step computed against this step's residency (the horizon IS that
         computation, so it is never done twice)."""
-        # a config whose policy id is NOT in this step's compiled set must
-        # not silently run as some other policy (a mislabeled lane in a
-        # stacked sweep would be wrong science with no diagnostic).  A jit
-        # step cannot raise, so an invalid lane trips the livelock guard
-        # on its first step: the run terminates immediately with every
-        # stream unfinished and ``extras["truncated"] = True`` — the flag
-        # every harness already refuses to compare.
-        ok_id = (
-            (cfg.policy >= 0) & (cfg.policy <= max_id)
-            & id_valid[jnp.clip(cfg.policy, 0, max_id)]
-        )
-        t2 = state.t + jnp.where(ok_id, dt, cfg.max_time + 1.0)
-        pol_local = lookup[jnp.clip(cfg.policy, 0, max_id)]
-        is_coop = coop_flags[pol_local] if has_coop else False
-        # supersaturation of this lane (pool below the scans' aggregate
-        # plan-window bytes): selects the wake-exact jump model in the
-        # horizon tail and the matching pop cap at the macro grant
-        sat = cfg.capacity_bytes < sat_bytes
-
-        # ============ CPU: consume up to the first absent trigger =========
-        (active, length, rate, _cols, start, cur, end, eps, frontier,
-         _fpidx, _ftrig, fneed) = view
-        # tie-break jitter for the LRU clock: every touch/load in one step
-        # would otherwise share the exact timestamp t2, and eviction would
-        # break those ties by page index — a SYSTEMATIC bias that carves
-        # the pool into a stable always-evicted side and a resident elite
-        # whose hit rate the event engine (with its total event-order
-        # recency) never reaches.  A deterministic per-(page, step) hash
-        # spanning _JIT_STEPS fine-step-lengths reproduces the engine's
-        # order noise (its touch events spread over multi-step burst
-        # intervals, so recency may genuinely invert across a few
-        # neighbouring steps) while staying pure for jit/vmap (no RNG
-        # state).  The amplitude is calibrated against the event engine at
-        # the small-pool points, in units of the FINE step — a horizon
-        # macro-step must not inflate it.
-        jit_p = _JIT_STEPS * dt_ref * _u01(jnp.arange(P, dtype=jnp.uint32),
-                                           state.steps, 40503)
-        w_pidx, w_trig, w_need, w_dist = win                # (S, C, W+1)
-        # per-(stream, query) CPU-rate skew: the event engine's burst
-        # granularity paces each scan on its own event clock, so two scans
-        # at the same position drift apart within a query; the fluid step
-        # advances them in perfect lockstep, freezing phase alignments
-        # that inflate sharing at tiny pools (zero-mean per-STEP noise
-        # integrates away — the drift must be sustained within a query to
-        # outrun a small pool's residency window, while a large pool still
-        # tolerates it, exactly like the engine).  Deterministic hash of
-        # (stream, query): pure, vmap-safe, zero-mean across queries.
-        ur = _u01(jnp.arange(S, dtype=jnp.uint32), state.qidx, 48271)
-        rate_j = rate * (1.0 + _RATE_JIT * (2.0 * ur - 1.0))
-        adv_lim = adv_limit(win, state.resident) if adv_lim_in is None \
-            else adv_lim_in
-        runnable = active & (adv_lim > 0.0)
-        remaining = length - state.pos
-        adv_io = jnp.where(
-            runnable,
-            jnp.minimum(jnp.minimum(rate_j * dt, remaining), adv_lim),
-            0.0,
-        )
-        adv_io = jnp.maximum(adv_io, 0.0)
-
-        margin = jnp.maximum(0.5, 3e-5 * length)
-        finished_io = runnable & (remaining - adv_io <= margin)
-
-        # ============ cooperative CPU model (compiled on demand) ==========
-        if has_coop:
-            cstate: coop_mod.CoopState = state.pstate[coop_idx]
-            q_tab = q_table[s_idx, jnp.clip(state.qidx, 0, Q - 1)]
-            coop_cpu = coop_mod.cpu_phase(
-                cc, cstate, active=active, start=start, end=end,
-                cols=_cols, q_tab=q_tab, rate_j=rate_j, dt=dt,
-                credit_cap=rate_j * dt, resident=state.resident,
-                page_col=page_col, page_valid=page_valid, s_idx=s_idx,
+        with phases.phase("cpu"):
+            # a config whose policy id is NOT in this step's compiled set must
+            # not silently run as some other policy (a mislabeled lane in a
+            # stacked sweep would be wrong science with no diagnostic).  A jit
+            # step cannot raise, so an invalid lane trips the livelock guard
+            # on its first step: the run terminates immediately with every
+            # stream unfinished and ``extras["truncated"] = True`` — the flag
+            # every harness already refuses to compare.
+            ok_id = (
+                (cfg.policy >= 0) & (cfg.policy <= max_id)
+                & id_valid[jnp.clip(cfg.policy, 0, max_id)]
             )
-            adv = _sel(is_coop, coop_cpu.adv, adv_io)
-            finished = _sel(is_coop, coop_cpu.finished, finished_io)
-        else:
-            adv, finished = adv_io, finished_io
-        # invalid-lane freeze (see ok_id above): no consumption, no
-        # completions — the lane must end truncated, not half-run
-        adv = jnp.where(ok_id, adv, 0.0)
-        finished = finished & ok_id
-        cur2_pre = cur + adv_io
+            t2 = state.t + jnp.where(ok_id, dt, cfg.max_time + 1.0)
+            pol_local = lookup[jnp.clip(cfg.policy, 0, max_id)]
+            is_coop = coop_flags[pol_local] if has_coop else False
+            # supersaturation of this lane (pool below the scans' aggregate
+            # plan-window bytes): selects the wake-exact jump model in the
+            # horizon tail and the matching pop cap at the macro grant
+            sat = cfg.capacity_bytes < sat_bytes
 
-        qidx2 = state.qidx + finished.astype(jnp.int32)
-        pos2 = jnp.where(finished, 0.0, state.pos + adv)
-        newly_done = (qidx2 >= n_q) & (state.stream_done_t < 0)
-        stream_done_t2 = jnp.where(newly_done, t2, state.stream_done_t)
-
-        # speed estimation on the engine's report cadence, not per step: a
-        # per-step EWMA collapses a blocked scan's estimate toward zero in
-        # a few ms, which sends the very pages it waits for to far-future
-        # buckets.  The engine instead measures (Δvirt_pos / Δt) between
-        # consecutive bursts — stall time folded in, progressless intervals
-        # skipped — so the estimate tracks the *effective* scan rate.  The
-        # array analogue updates once per PBM slice from the cumulative
-        # consumed-tuples counter (refresh step below).
-        consumed2 = state.consumed + adv
-        next_rate = q_rate[s_idx, jnp.clip(qidx2, 0, Q - 1)]
-        speed1 = jnp.where(finished, next_rate, state.speed)  # fresh scan
-        if refresh:
-            prog = consumed2 - state.consumed_ref
-            # a wake-exact refresh step may stand in for several slices:
-            # the burst-report cadence is then slices_u slice lengths
-            if slices_u is None:
-                inst = prog / time_slice_f
-            else:
-                inst = prog / (time_slice_f
-                               * slices_u.astype(jnp.float32))
-            speed2 = jnp.where(
-                active & (prog > _PROG_MIN) & ~finished,
-                _BURST_W * next_rate + (1.0 - _BURST_W) * inst,
-                speed1,
-            )
-            consumed_ref2 = consumed2
-        else:
-            speed2 = speed1
-            consumed_ref2 = state.consumed_ref
-
-        # pages consumed this step: resident windowed pages whose trigger
-        # the cursor crossed (same predicate the next view's frontier uses,
-        # so crossing and frontier advance can never disagree)
-        crossed = (
-            w_need[:, :, :W]
-            & runnable[:, None, None]
-            & state.resident[w_pidx[:, :, :W]]
-            & (w_trig[:, :, :W] < (cur2_pre - eps)[:, None, None])
-        )
-        if has_coop:
-            crossed = crossed & jnp.logical_not(is_coop)
-        cross_pidx = w_pidx[:, :, :W]
-        # engine parity: the LRU clock ticks when a page is consumed, and
-        # only the pages of the running burst are pinned — a blocked scan
-        # pins nothing, a mid-page scan pins nothing it already consumed
-        last_used2 = state.last_used.at[cross_pidx].max(
-            jnp.where(crossed, t2 + jit_p[cross_pidx], -INF)
-        )
-        if has_coop:
-            touched_coop = is_coop & coop_cpu.consumed_pages
-            last_used2 = jnp.where(touched_coop, t2 + jit_p, last_used2)
-
-        # ================= post-advance view (I/O demand) =================
-        view2 = query_view(qidx2, pos2)
-        (active2, _l2, rate2, cols2, start2, cur2, end2, eps2, frontier2,
-         fpidx2, ftrig2, need2) = view2
-
-        # request set = the engine's plan window: the blocking page (the
-        # trigger the cursor sits on) plus the next ~K *plan entries* in
-        # (trigger, column, page) order.  Crucially a far-trigger frontier
-        # page (a sparse column whose next boundary is many dense-pages
-        # ahead) is NOT demanded early: the engine only requests it once it
-        # enters the plan window.  Early fetches age out of a small pool
-        # and reload — churn the engine does not have.
-        inv_tpp = 1.0 / col_tpp[None, :]
-        dens = jnp.sum(jnp.where(need2, inv_tpp, 0.0), axis=1, keepdims=True)
-        # one fused scatter over K_LOOP+1 plan-window slots per (stream,
-        # column); K_LOOP bounds the per-column scatter walk
-        ks = jnp.arange(K_LOOP + 1)                    # (K_LOOP+1,)
-        pf_local = frontier2[:, :, None] + ks[None, None, :]
-        exists = (pf_local < col_npages[None, :, None]) & need2[:, :, None]
-        pf_pidx = col_start[None, :, None] + jnp.minimum(
-            pf_local, col_npages[None, :, None] - 1
-        )
-        pf_trig = jnp.maximum(page_first[pf_pidx], start2[:, None, None])
-        exists &= page_first[pf_pidx] < end2[:, None, None]
-        # the engine prefetches the next K *plan entries* — an entry-COUNT
-        # window over the (trigger, column, page) plan order, resident
-        # entries included in the budget.  The count cut matters: it can
-        # leave a same-trigger group partner just outside the window, to be
-        # discovered only at the next wake (see the request gate below) —
-        # the separation behind the engine's small-pool churn.
-        e_trig = jnp.where(exists, pf_trig, INF)
-        flat_trig = e_trig.reshape(S, C * (K_LOOP + 1))
-        flat_ord = jnp.argsort(jnp.argsort(flat_trig, axis=1), axis=1)
-        # argsort twice = rank in the plan order; jnp.argsort is stable, so
-        # ties resolve by (column, page) flat position — the engine's plan
-        # sort key (trigger, column, index)
-        rank = flat_ord.reshape(S, C, K_LOOP + 1)
-        # the k=0 slot (the frontier itself) is always requested once its
-        # trigger reaches the cursor — the blocking demand — even with
-        # prefetch disabled
-        blocking = (ks[None, None, :] == 0) & (
-            pf_trig <= (cur2 + eps2)[:, None, None]
-        )
-        # request cadence gate, engine parity: a scan issues requests only
-        # while it runs (burst ends) and at the instant it blocks — a
-        # blocked scan's window is FROZEN until its demand loads.  Pages
-        # entering the window mid-wait are not requested until the wake,
-        # which is what separates group partners into distant queue
-        # positions (continuous re-wanting erased that separation and with
-        # it most of the engine's small-pool churn).
-        ug = _u01(jnp.arange(S, dtype=jnp.uint32), state.steps,
-                  3266489917, idx_mult=2246822519)
-        # the engine's refresh rate follows its wake rate, which rises
-        # with I/O pressure: scale by the lifetime duty cycle (a stalled
-        # scan wakes per demand load ~= often; a CPU-bound scan re-issues
-        # only per burst, where the continuous window already covers it)
-        duty_g = jnp.clip(
-            (state.consumed / jnp.maximum(state.t, 1e-9))
-            / jnp.maximum(rate, 1.0),
-            0.0, 1.0,
-        )
-        gate_p = _GATE_P * (1.0 - duty_g)
-        if horizon:
-            # a macro-step stands in for h_u fine steps: the blocked-scan
-            # window-refresh is a per-fine-step Bernoulli process, so the
-            # macro step fires it with the compounded probability —
-            # otherwise longer jumps would silently freeze blocked
-            # windows.  h_u == 1 keeps gate_p exactly (bit-parity with
-            # the fixed stepper; pow would round at the ulp level).
-            gate_p = jnp.where(
-                h_u == 1, gate_p,
-                1.0 - (1.0 - gate_p) ** h_u.astype(jnp.float32),
-            )
-        gate = (
-            (adv_io > 0.0) | (state.steps == 0) | finished | (ug < gate_p)
-        )
-        # per-policy readahead width (ArrayPolicy.request_window): e.g. the
-        # array LRU widens the engine's 8-entry window at single-table
-        # deep thrash — a policy-provided value, indexed by the lane's id
-        if n_pol == 1:
-            k_win = int(k_wins_np[0])
-        else:
-            k_win = jnp.asarray(k_wins_np)[pol_local]
-        # the blocking demand is exempt from the gate: the engine requests
-        # the page it blocks on unconditionally, and a frontier page that
-        # was resident at the block transition but evicted during the wait
-        # would otherwise stall for a geometric number of steps before its
-        # demand is even queued
-        ok = exists & (((rank <= k_win) & gate[:, None, None]) | blocking)
-        kb = jnp.where(ks == 0, 31, jnp.clip(K_LOOP + 1 - ks, 1, 30))
-        bonus = jnp.full(P, -1, jnp.int32).at[pf_pidx].max(
-            jnp.where(ok, kb[None, None, :], -1)
-        )
-        in_plan_window = (bonus >= 0) & ~state.resident & page_valid
-        # FIFO request queue, array-form: every page keeps the step at which
-        # it was first requested, and — engine parity — the request STAYS
-        # queued after the cursor's plan window moves past it: the engine
-        # only drops an entry when the page loads or the requesting query
-        # ends.  Those stale early fetches (served hundreds of grants after
-        # they were issued, evicted before their scan arrives, re-requested)
-        # are most of the engine's small-pool churn; forgetting them made
-        # the array 15-25% too fast below 20% buffer.  The array clears
-        # stamps on load, and at each slice refresh for pages no active
-        # scan is interested in (the query-end drop, slice-quantised).
-        wanted = in_plan_window | (
-            (state.req_step != _REQ_NONE) & ~state.resident & page_valid
-        )
-        req_step2 = jnp.where(
-            wanted, jnp.minimum(state.req_step, state.steps + 1), _REQ_NONE
-        )
-        # strict FIFO by first-wanted step (engine parity: a demand request
-        # does NOT jump ahead of older readahead in the serial queue).
-        # Ties within one step's cohort resolve by a hash fixed at stamp
-        # time — the engine's enqueue order is equally arbitrary, but a
-        # deterministic page-index order would serve the same streams first
-        # every cohort and freeze fine phase alignments between overlapping
-        # scans that the event engine's noise dissolves.  The bonus only
-        # defines membership of the wanted set, not the service order.
-        stamp_age = jnp.clip(state.steps + 1 - req_step2, 0, 32767)
-        # within-cohort service order: the engine enqueues a woken scan's
-        # whole window CONTIGUOUSLY (one event = adjacent queue slots), and
-        # the order of scans within one array step is event-timing noise.
-        # So the cohort rank is (stream hash, plan rank) — a per-stream
-        # block — fixed at stamp time like the engine's queue position.
-        s_ord = (512.0 * _u01(jnp.arange(S, dtype=jnp.uint32),
-                              state.steps, 40503)).astype(jnp.int32)
-        slot = s_ord[:, None, None] * 64 + jnp.clip(rank, 0, 63)
-        tie_now = jnp.full(P, 32767, jnp.int32).at[pf_pidx].min(
-            jnp.where(ok, slot, 32767)
-        )
-        new_stamp = wanted & (state.req_step == _REQ_NONE)
-        req_tie2 = jnp.where(new_stamp, tie_now, state.req_tie)
-        tie_blk = 32767 - req_tie2
-        tie_idx = 32767 - jnp.arange(P, dtype=jnp.int32)
-        # per-policy cohort order (ArrayPolicy.fifo_tie): the array LRU
-        # tracks the engine best with the stream-block order; estimate-
-        # driven policies with the plan-deterministic index order (their
-        # scores already absorb the timing noise)
-        tie_tab = [tie_idx if p.fifo_tie == "plan" else tie_blk
-                   for p in policies]
-        if n_pol == 1:
-            tie15 = tie_tab[0]
-        else:
-            tie15 = jnp.stack(tie_tab)[pol_local]
-        load_key = jnp.where(wanted, stamp_age * 32768 + tie15, -1)
-
-        # ================= I/O server: budgeted admission =================
-        used = jnp.sum(page_size * state.resident)
-        free = cfg.capacity_bytes - used
-        # engine parity: a running scan pins the pages of its current CPU
-        # burst — the last ~segment_pages plan entries behind the cursor —
-        # for the burst's duration; a blocked scan pins nothing, so pools
-        # far below streams x columns pages cannot livelock.  The array
-        # analogue pins pages whose trigger lies within a segment length
-        # (segment_pages plan entries ~= seg/dens tuples) behind the cursor
-        # of a stream that advanced this step.
-        seg_len = _SEG_PAGES / jnp.maximum(dens[:, 0], 1e-30)  # (S,) tuples
-        bk = jnp.arange(_SEG_WIN)                           # (B,)
-        b_local = frontier2[:, :, None] - 1 - bk[None, None, :]
-        b_pidx = col_start[None, :, None] + jnp.clip(
-            b_local, 0, col_npages[None, :, None] - 1
-        )
-        b_trig = jnp.maximum(page_first[b_pidx], start2[:, None, None])
-        burst = (
-            (b_local >= 0)
-            & (cols2 & active2[:, None])[:, :, None]
-            & runnable[:, None, None]
-            & (b_trig >= (cur2 - seg_len)[:, None, None])
-        )
-        pin = jnp.zeros(P, jnp.int32).at[b_pidx].max(burst.astype(jnp.int32))
-        evictable_io = state.resident & (pin == 0) & page_valid
-
-        # ============ cooperative I/O model (compiled on demand) ==========
-        if has_coop:
-            done3 = coop_mod.clear_on_query_change(
-                coop_cpu.done, coop_cpu.finished
-            )
-            q_tab2 = q_table[s_idx, jnp.clip(qidx2, 0, Q - 1)]
-            coop_io = coop_mod.io_phase(
-                cc, done=done3, cur_chunk=coop_cpu.cur_chunk,
-                inflight=cstate.inflight, pin_pages=coop_cpu.pin_pages,
-                active=active2, start=start2, end=end2, cols=cols2,
-                q_tab=q_tab2, resident=state.resident, free=free,
-                page_chunk_sizes=page_size, page_col=page_col,
-                page_valid=page_valid, n_streams=S,
-            )
-            load_key = _sel(is_coop, coop_io.load_key, load_key)
-            wanted = _sel(is_coop, coop_io.wanted, wanted)
-            evictable = _sel(is_coop, coop_io.evictable, evictable_io)
-        else:
-            evictable = evictable_io
-        evictable_bytes = jnp.sum(page_size * evictable)
-        headroom = free + evictable_bytes
-        credit = state.io_credit + cfg.bandwidth * dt
-
-        # an invalid lane's server grants nothing (ok_id freeze)
-        budget = jnp.where(ok_id, jnp.minimum(credit, headroom), 0.0)
-        if horizon:
-            # serial-server causality over a macro-step: credit accrued
-            # while the queue was EMPTY must not fund requests that only
-            # appear at the end of the jump (the engine's idle server
-            # banks about one fine step of work, no more).  Cap this
-            # step's serviceable bytes at the queue content present when
-            # the jump began plus one fine step's credit — which also
-            # makes the cap vacuous at h_u == 1 (fixed-stepper parity).
-            # The queue bytes were computed by the previous step's
-            # horizon (they ARE its io-credit candidate) and carried.
-            pend_bytes0 = pend_in
-            if has_coop:
-                infl0 = cstate.inflight
-                pend_c0 = (
-                    (cc.page_chunk == jnp.clip(infl0, 0, cc.n_chunks - 1))
-                    & (infl0 >= 0) & ~state.resident & page_valid
-                )
-                pend_bytes0 = _sel(
-                    is_coop, jnp.sum(page_size * pend_c0), pend_bytes0
-                )
-            budget = jnp.minimum(
-                budget,
-                state.io_credit + pend_bytes0 + cfg.bandwidth * dt_ref,
-            )
-            # budgeted FIFO pop as ONE batched grant op — the macro grant
-            # covers an h_io-fine-step jump without n_rounds serial
-            # argmax passes over the page axis (Pallas MXU prefix kernel
-            # on TPU, top_k + prefix-product oracle elsewhere).
-            # Semantics match the fixed loop: strict head-of-line (the
-            # first page that does not fit blocks the rest), ties by
-            # lower page index, _LOAD_MAX pops per fine step stood in
-            # for — all inside the static n_rounds top-k window, which
-            # therefore also caps a multi-step grant's pop count (the
-            # byte budget of an h_io-step jump fits the window at the
-            # validated operating points; credit a short window leaves
-            # unspent banks for the next step, like the fixed path's
-            # leftover credit).
-            if wake_exact:
-                # non-saturated lanes keep the PR-9 pop cap bit-for-bit;
-                # a wake-exact supersaturated jump needs every pop its
-                # fine steps would have taken
-                pop_cap = jnp.where(sat, n_rounds, n_rounds_io)
-            else:
-                pop_cap = n_rounds
-            pops = jnp.minimum(h_u * _LOAD_MAX, pop_cap)
-            load_mask, load_bytes, n_load = kops.fifo_grant(
-                load_key, page_size, budget, pops, vmax=n_rounds,
-                page_axis=page_axis,
-            )
-            cand = cand_ok = None
-        else:
-            # the server grants at most ~credit bytes (a handful of pages)
-            # per step: pop the FIFO head a few times instead of sorting
-            # anything.  Head-of-line semantics: the first page that does
-            # not fit blocks the rest of the queue, like the engine's
-            # serial server.
-            kcur = load_key
-            taken = jnp.float32(0.0)
-            open_ = jnp.bool_(True)
-            arange_p = jnp.arange(P)
-            hit = jnp.zeros(P, bool)
-            cand = []
-            cand_ok = []
-            for _ in range(n_rounds):
-                j = jnp.argmax(kcur)
-                ok_j = open_ & (kcur[j] >= 0) & (
-                    taken + page_size[j] <= budget
-                )
-                open_ = ok_j
-                is_j = arange_p == j   # arithmetic mask: fuses, scatter won't
-                hit = hit | (is_j & ok_j)
-                taken = taken + jnp.where(ok_j, page_size[j], 0.0)
-                kcur = jnp.where(is_j, -1, kcur)
-                cand.append(j)
-                cand_ok.append(ok_j)
-            load_mask = hit
-            cand = jnp.stack(cand)                     # (n_rounds,)
-            cand_ok = jnp.stack(cand_ok)
-            load_bytes = taken
-            n_load = jnp.sum(cand_ok)
-
-        # bank leftover credit instead of zeroing it whenever the request
-        # queue went momentarily empty — that dropped the partially-funded
-        # head-of-line load and made effective bandwidth dip below
-        # cfg.bandwidth on bursty workloads.  The cap stays at 4 pages
-        # while requests remain unserved (funding the next grants); with an
-        # empty queue one page-time is kept, compensating the idle server's
-        # ability to start a load the instant a request arrives mid-step
-        # (the engine's serial server never banks more idle time than that).
-        leftover = credit - load_bytes
-        starved_io = jnp.sum(wanted & ~load_mask) > 0
-        credit_cap = jnp.where(starved_io, 4 * max_page, max_page)
-        io_credit2 = jnp.minimum(leftover, credit_cap)
-
-        # engine speed-estimate DIPS: the dict engine's per-burst EWMA
-        # crashes toward the effective rate at every stall exit, and pages
-        # pushed during a dip land in far-future buckets — prime eviction
-        # victims although their consumption is imminent.  That mis-push
-        # churn (7% of engine loads at 40% buffer, ~20% at 10%) never
-        # happens with a smooth estimate, leaving the array faster than
-        # the machine it models.  Sample the dips per (stream, step).
-        ud = _u01(jnp.arange(S, dtype=jnp.uint32), state.steps, 3266489917)
-        eff_rate = jnp.clip(
-            state.consumed / jnp.maximum(state.t, 1e-9),
-            1.0, None,
-        )
-        dip_p = jnp.float32(_DIP_P)
-        if horizon:
-            # the dip is a per-FINE-step Bernoulli (calibrated against
-            # the engine's stall-exit EWMA crashes): a macro-step
-            # standing in for h_u fine steps fires it with the
-            # compounded probability, like the request gate above —
-            # h_u == 1 keeps _DIP_P exactly (fixed-stepper bit parity).
-            # Without this the wake-exact path under-samples dips and
-            # ran ~18% too fast at the 10% deep-thrash point.
-            dip_p = jnp.where(
-                h_u == 1, dip_p,
-                1.0 - (1.0 - dip_p) ** h_u.astype(jnp.float32),
-            )
-        speed_push = jnp.where(
-            ud < dip_p, jnp.minimum(_DIP_DEPTH * eff_rate, speed2), speed2
-        )
-
-        # ================= policy hooks + batched eviction ================
-        # pages whose consumption state changed this step (feeds the churn
-        # diagnostic below and PBM's within-slice update set)
-        was_crossed = jnp.zeros(P, bool).at[cross_pidx].max(crossed)
-        if has_coop:
-            was_crossed = _sel(is_coop, coop_cpu.consumed_pages,
-                               was_crossed)
-        if horizon:
-            # compacted within-slice update set: the padded (S, C, W)
-            # cross window grows with the horizon's longer trigger
-            # lookahead, but the pages that actually changed stay few —
-            # hand the policies a dense id list instead of the padded
-            # window (duplicates and the fill id carry ``upd_on`` False
-            # or an identical update value, so the min-combining scatter
-            # is unchanged; overflow beyond the static cap merely leaves
-            # a page's bucket stale until the slice refresh).
-            upd_mask = (was_crossed | load_mask) & page_valid
-            upd_pages = jnp.nonzero(upd_mask, size=min(P, 512),
-                                    fill_value=0)[0]
-            upd_on = upd_mask[upd_pages]
-        else:
-            upd_pages = upd_on = None
-        ctx = StepCtx(
-            spec=spec, refresh=refresh, time_slice=time_slice_f, now=t2,
-            steps=state.steps, slices_done=state.slices_done,
-            slices_elapsed=slices_u, dt=dt,
-            page_first=page_first, page_last=page_last, page_col=page_col,
-            page_valid=page_valid, resident=state.resident,
-            last_used=last_used2, load_mask=load_mask, load_cand=cand,
-            load_ok=cand_ok, cross_pidx=cross_pidx, crossed=crossed,
-            upd_pages=upd_pages, upd_on=upd_on,
-            active=active2, cols=cols2, cur=cur2, end=end2, start=start2,
-            eps=eps2, rate=rate2, speed_push=speed_push,
-            coop=coop_io if has_coop else None,
-        )
-        pstate2 = []
-        for p, ps in zip(policies, state.pstate):
-            if p.cooperative:
-                # the cooperative substrate owns its state transitions
-                pstate2.append(coop_mod.CoopState(
-                    done=done3, cur_chunk=coop_cpu.cur_chunk,
-                    chunk_pos=coop_cpu.chunk_pos, credit=coop_cpu.credit,
-                    inflight=coop_io.inflight,
-                ))
-            else:
-                pstate2.append(p.on_consume(p.on_request(ps, ctx), ctx))
-        keys = [p.score_victims(ps, ctx)
-                for p, ps in zip(policies, pstate2)]
-        if n_pol == 1:
-            key = keys[0]
-        else:
-            key = jnp.stack(keys)[pol_local]
-
-        if refresh:
-            # query-end request drop, slice-quantised: pending requests for
-            # pages no active scan is interested in leave the queue
-            interested = (ctx.eta_estimate() < BIG_CUT) & page_valid
-            req_step2 = jnp.where(interested, req_step2, _REQ_NONE)
-            slices_done2 = state.slices_done + (
-                jnp.int32(1) if slices_u is None else slices_u
-            )
-        else:
-            slices_done2 = state.slices_done
-
-        # engine parity: evictions are amortised in batches (>= 16 pages),
-        # so a triggered eviction frees up to a whole batch, not one page.
-        # The cooperative server instead evicts exactly the victims its
-        # chunk needs (ABM plans evictions per load decision).
-        batch = jnp.minimum(16 * max_page, cfg.capacity_bytes)
-        need_io = jnp.where(
-            load_bytes > free,
-            jnp.minimum(jnp.maximum(load_bytes, batch) - free,
-                        evictable_bytes),
-            0.0,
-        )
-        if has_coop:
-            need_coop = jnp.where(
-                load_bytes > free,
-                jnp.minimum(load_bytes - free, evictable_bytes),
+            # ============ CPU: consume up to the first absent trigger =========
+            (active, length, rate, _cols, start, cur, end, eps, frontier,
+             _fpidx, _ftrig, fneed) = view
+            # tie-break jitter for the LRU clock: every touch/load in one step
+            # would otherwise share the exact timestamp t2, and eviction would
+            # break those ties by page index — a SYSTEMATIC bias that carves
+            # the pool into a stable always-evicted side and a resident elite
+            # whose hit rate the event engine (with its total event-order
+            # recency) never reaches.  A deterministic per-(page, step) hash
+            # spanning _JIT_STEPS fine-step-lengths reproduces the engine's
+            # order noise (its touch events spread over multi-step burst
+            # intervals, so recency may genuinely invert across a few
+            # neighbouring steps) while staying pure for jit/vmap (no RNG
+            # state).  The amplitude is calibrated against the event engine at
+            # the small-pool points, in units of the FINE step — a horizon
+            # macro-step must not inflate it.
+            jit_p = _JIT_STEPS * dt_ref * _u01(jnp.arange(P, dtype=jnp.uint32),
+                                               state.steps, 40503)
+            w_pidx, w_trig, w_need, w_dist = win                # (S, C, W+1)
+            # per-(stream, query) CPU-rate skew: the event engine's burst
+            # granularity paces each scan on its own event clock, so two scans
+            # at the same position drift apart within a query; the fluid step
+            # advances them in perfect lockstep, freezing phase alignments
+            # that inflate sharing at tiny pools (zero-mean per-STEP noise
+            # integrates away — the drift must be sustained within a query to
+            # outrun a small pool's residency window, while a large pool still
+            # tolerates it, exactly like the engine).  Deterministic hash of
+            # (stream, query): pure, vmap-safe, zero-mean across queries.
+            ur = _u01(jnp.arange(S, dtype=jnp.uint32), state.qidx, 48271)
+            rate_j = rate * (1.0 + _RATE_JIT * (2.0 * ur - 1.0))
+            adv_lim = adv_limit(win, state.resident) if adv_lim_in is None \
+                else adv_lim_in
+            runnable = active & (adv_lim > 0.0)
+            remaining = length - state.pos
+            adv_io = jnp.where(
+                runnable,
+                jnp.minimum(jnp.minimum(rate_j * dt, remaining), adv_lim),
                 0.0,
             )
-            need_free = _sel(is_coop, need_coop, need_io)
-        else:
-            need_free = need_io
-        evict = kops.batched_evict(key, page_size, evictable, need_free,
-                                   vmax=vmax, page_axis=page_axis)
+            adv_io = jnp.maximum(adv_io, 0.0)
 
-        resident2 = (state.resident & ~evict) | load_mask
-        last_used3 = jnp.where(load_mask, t2 + jit_p, last_used2)
-        # churn diagnostic: a page evicted while still "fresh" (loaded but
-        # never consumed since) was a wasted load
-        fresh2 = jnp.where(load_mask, True,
-                           state.fresh & ~was_crossed & resident2)
-        churn2 = state.churn + jnp.sum(state.fresh & evict & ~was_crossed)
-        req_step3 = jnp.where(load_mask, _REQ_NONE, req_step2)
-        demand_hit = load_mask & (bonus == 31)
-        if has_coop:
-            demand_hit = demand_hit & jnp.logical_not(is_coop)
+            margin = jnp.maximum(0.5, 3e-5 * length)
+            finished_io = runnable & (remaining - adv_io <= margin)
 
-        new_state = SimState(
-            resident=resident2,
-            last_used=last_used3,
-            req_step=req_step3,
-            req_tie=req_tie2,
-            fresh=fresh2,
-            qidx=qidx2,
-            pos=pos2,
-            speed=speed2,
-            consumed=consumed2,
-            consumed_ref=consumed_ref2,
-            stream_done_t=stream_done_t2,
-            t=t2,
-            steps=state.steps + 1,
-            slices_done=slices_done2,
-            io_credit=io_credit2,
-            io_bytes=state.io_bytes + load_bytes,
-            loads=state.loads + n_load,
-            loads_demand=state.loads_demand + jnp.sum(demand_hit),
-            churn=churn2,
-            pstate=tuple(pstate2),
-        )
+            # ============ cooperative CPU model (compiled on demand) ==========
+            if has_coop:
+                cstate: coop_mod.CoopState = state.pstate[coop_idx]
+                q_tab = q_table[s_idx, jnp.clip(state.qidx, 0, Q - 1)]
+                coop_cpu = coop_mod.cpu_phase(
+                    cc, cstate, active=active, start=start, end=end,
+                    cols=_cols, q_tab=q_tab, rate_j=rate_j, dt=dt,
+                    credit_cap=rate_j * dt, resident=state.resident,
+                    page_col=page_col, page_valid=page_valid, s_idx=s_idx,
+                )
+                adv = _sel(is_coop, coop_cpu.adv, adv_io)
+                finished = _sel(is_coop, coop_cpu.finished, finished_io)
+            else:
+                adv, finished = adv_io, finished_io
+            # invalid-lane freeze (see ok_id above): no consumption, no
+            # completions — the lane must end truncated, not half-run
+            adv = jnp.where(ok_id, adv, 0.0)
+            finished = finished & ok_id
+            cur2_pre = cur + adv_io
+
+            qidx2 = state.qidx + finished.astype(jnp.int32)
+            pos2 = jnp.where(finished, 0.0, state.pos + adv)
+            newly_done = (qidx2 >= n_q) & (state.stream_done_t < 0)
+            stream_done_t2 = jnp.where(newly_done, t2, state.stream_done_t)
+
+            # speed estimation on the engine's report cadence, not per step: a
+            # per-step EWMA collapses a blocked scan's estimate toward zero in
+            # a few ms, which sends the very pages it waits for to far-future
+            # buckets.  The engine instead measures (Δvirt_pos / Δt) between
+            # consecutive bursts — stall time folded in, progressless intervals
+            # skipped — so the estimate tracks the *effective* scan rate.  The
+            # array analogue updates once per PBM slice from the cumulative
+            # consumed-tuples counter (refresh step below).
+            consumed2 = state.consumed + adv
+            next_rate = q_rate[s_idx, jnp.clip(qidx2, 0, Q - 1)]
+            speed1 = jnp.where(finished, next_rate, state.speed)  # fresh scan
+            if refresh:
+                prog = consumed2 - state.consumed_ref
+                # a wake-exact refresh step may stand in for several slices:
+                # the burst-report cadence is then slices_u slice lengths
+                if slices_u is None:
+                    inst = prog / time_slice_f
+                else:
+                    inst = prog / (time_slice_f
+                                   * slices_u.astype(jnp.float32))
+                speed2 = jnp.where(
+                    active & (prog > _PROG_MIN) & ~finished,
+                    _BURST_W * next_rate + (1.0 - _BURST_W) * inst,
+                    speed1,
+                )
+                consumed_ref2 = consumed2
+            else:
+                speed2 = speed1
+                consumed_ref2 = state.consumed_ref
+
+            # pages consumed this step: resident windowed pages whose trigger
+            # the cursor crossed (same predicate the next view's frontier uses,
+            # so crossing and frontier advance can never disagree)
+            crossed = (
+                w_need[:, :, :W]
+                & runnable[:, None, None]
+                & state.resident[w_pidx[:, :, :W]]
+                & (w_trig[:, :, :W] < (cur2_pre - eps)[:, None, None])
+            )
+            if has_coop:
+                crossed = crossed & jnp.logical_not(is_coop)
+            cross_pidx = w_pidx[:, :, :W]
+            # engine parity: the LRU clock ticks when a page is consumed, and
+            # only the pages of the running burst are pinned — a blocked scan
+            # pins nothing, a mid-page scan pins nothing it already consumed
+            last_used2 = state.last_used.at[cross_pidx].max(
+                jnp.where(crossed, t2 + jit_p[cross_pidx], -INF)
+            )
+            if has_coop:
+                touched_coop = is_coop & coop_cpu.consumed_pages
+                last_used2 = jnp.where(touched_coop, t2 + jit_p, last_used2)
+
+        # ================= post-advance view (I/O demand) =================
+        with phases.phase("demand"):
+            view2 = query_view(qidx2, pos2)
+            (active2, _l2, rate2, cols2, start2, cur2, end2, eps2, frontier2,
+             fpidx2, ftrig2, need2) = view2
+
+            # request set = the engine's plan window: the blocking page (the
+            # trigger the cursor sits on) plus the next ~K *plan entries* in
+            # (trigger, column, page) order.  Crucially a far-trigger frontier
+            # page (a sparse column whose next boundary is many dense-pages
+            # ahead) is NOT demanded early: the engine only requests it once it
+            # enters the plan window.  Early fetches age out of a small pool
+            # and reload — churn the engine does not have.
+            inv_tpp = 1.0 / col_tpp[None, :]
+            dens = jnp.sum(jnp.where(need2, inv_tpp, 0.0), axis=1, keepdims=True)
+            # one fused scatter over K_LOOP+1 plan-window slots per (stream,
+            # column); K_LOOP bounds the per-column scatter walk
+            ks = jnp.arange(K_LOOP + 1)                    # (K_LOOP+1,)
+            pf_local = frontier2[:, :, None] + ks[None, None, :]
+            exists = (pf_local < col_npages[None, :, None]) & need2[:, :, None]
+            pf_pidx = col_start[None, :, None] + jnp.minimum(
+                pf_local, col_npages[None, :, None] - 1
+            )
+            pf_trig = jnp.maximum(page_first[pf_pidx], start2[:, None, None])
+            exists &= page_first[pf_pidx] < end2[:, None, None]
+            # the engine prefetches the next K *plan entries* — an entry-COUNT
+            # window over the (trigger, column, page) plan order, resident
+            # entries included in the budget.  The count cut matters: it can
+            # leave a same-trigger group partner just outside the window, to be
+            # discovered only at the next wake (see the request gate below) —
+            # the separation behind the engine's small-pool churn.
+            e_trig = jnp.where(exists, pf_trig, INF)
+            flat_trig = e_trig.reshape(S, C * (K_LOOP + 1))
+            flat_ord = jnp.argsort(jnp.argsort(flat_trig, axis=1), axis=1)
+            # argsort twice = rank in the plan order; jnp.argsort is stable, so
+            # ties resolve by (column, page) flat position — the engine's plan
+            # sort key (trigger, column, index)
+            rank = flat_ord.reshape(S, C, K_LOOP + 1)
+            # the k=0 slot (the frontier itself) is always requested once its
+            # trigger reaches the cursor — the blocking demand — even with
+            # prefetch disabled
+            blocking = (ks[None, None, :] == 0) & (
+                pf_trig <= (cur2 + eps2)[:, None, None]
+            )
+            # request cadence gate, engine parity: a scan issues requests only
+            # while it runs (burst ends) and at the instant it blocks — a
+            # blocked scan's window is FROZEN until its demand loads.  Pages
+            # entering the window mid-wait are not requested until the wake,
+            # which is what separates group partners into distant queue
+            # positions (continuous re-wanting erased that separation and with
+            # it most of the engine's small-pool churn).
+            ug = _u01(jnp.arange(S, dtype=jnp.uint32), state.steps,
+                      3266489917, idx_mult=2246822519)
+            # the engine's refresh rate follows its wake rate, which rises
+            # with I/O pressure: scale by the lifetime duty cycle (a stalled
+            # scan wakes per demand load ~= often; a CPU-bound scan re-issues
+            # only per burst, where the continuous window already covers it)
+            duty_g = jnp.clip(
+                (state.consumed / jnp.maximum(state.t, 1e-9))
+                / jnp.maximum(rate, 1.0),
+                0.0, 1.0,
+            )
+            gate_p = _GATE_P * (1.0 - duty_g)
+            if horizon:
+                # a macro-step stands in for h_u fine steps: the blocked-scan
+                # window-refresh is a per-fine-step Bernoulli process, so the
+                # macro step fires it with the compounded probability —
+                # otherwise longer jumps would silently freeze blocked
+                # windows.  h_u == 1 keeps gate_p exactly (bit-parity with
+                # the fixed stepper; pow would round at the ulp level).
+                gate_p = jnp.where(
+                    h_u == 1, gate_p,
+                    1.0 - (1.0 - gate_p) ** h_u.astype(jnp.float32),
+                )
+            gate = (
+                (adv_io > 0.0) | (state.steps == 0) | finished | (ug < gate_p)
+            )
+            # per-policy readahead width (ArrayPolicy.request_window): e.g. the
+            # array LRU widens the engine's 8-entry window at single-table
+            # deep thrash — a policy-provided value, indexed by the lane's id
+            if n_pol == 1:
+                k_win = int(k_wins_np[0])
+            else:
+                k_win = jnp.asarray(k_wins_np)[pol_local]
+            # the blocking demand is exempt from the gate: the engine requests
+            # the page it blocks on unconditionally, and a frontier page that
+            # was resident at the block transition but evicted during the wait
+            # would otherwise stall for a geometric number of steps before its
+            # demand is even queued
+            ok = exists & (((rank <= k_win) & gate[:, None, None]) | blocking)
+            kb = jnp.where(ks == 0, 31, jnp.clip(K_LOOP + 1 - ks, 1, 30))
+            bonus = jnp.full(P, -1, jnp.int32).at[pf_pidx].max(
+                jnp.where(ok, kb[None, None, :], -1)
+            )
+            in_plan_window = (bonus >= 0) & ~state.resident & page_valid
+            # FIFO request queue, array-form: every page keeps the step at which
+            # it was first requested, and — engine parity — the request STAYS
+            # queued after the cursor's plan window moves past it: the engine
+            # only drops an entry when the page loads or the requesting query
+            # ends.  Those stale early fetches (served hundreds of grants after
+            # they were issued, evicted before their scan arrives, re-requested)
+            # are most of the engine's small-pool churn; forgetting them made
+            # the array 15-25% too fast below 20% buffer.  The array clears
+            # stamps on load, and at each slice refresh for pages no active
+            # scan is interested in (the query-end drop, slice-quantised).
+            wanted = in_plan_window | (
+                (state.req_step != _REQ_NONE) & ~state.resident & page_valid
+            )
+            req_step2 = jnp.where(
+                wanted, jnp.minimum(state.req_step, state.steps + 1), _REQ_NONE
+            )
+            # strict FIFO by first-wanted step (engine parity: a demand request
+            # does NOT jump ahead of older readahead in the serial queue).
+            # Ties within one step's cohort resolve by a hash fixed at stamp
+            # time — the engine's enqueue order is equally arbitrary, but a
+            # deterministic page-index order would serve the same streams first
+            # every cohort and freeze fine phase alignments between overlapping
+            # scans that the event engine's noise dissolves.  The bonus only
+            # defines membership of the wanted set, not the service order.
+            stamp_age = jnp.clip(state.steps + 1 - req_step2, 0, 32767)
+            # within-cohort service order: the engine enqueues a woken scan's
+            # whole window CONTIGUOUSLY (one event = adjacent queue slots), and
+            # the order of scans within one array step is event-timing noise.
+            # So the cohort rank is (stream hash, plan rank) — a per-stream
+            # block — fixed at stamp time like the engine's queue position.
+            s_ord = (512.0 * _u01(jnp.arange(S, dtype=jnp.uint32),
+                                  state.steps, 40503)).astype(jnp.int32)
+            slot = s_ord[:, None, None] * 64 + jnp.clip(rank, 0, 63)
+            tie_now = jnp.full(P, 32767, jnp.int32).at[pf_pidx].min(
+                jnp.where(ok, slot, 32767)
+            )
+            new_stamp = wanted & (state.req_step == _REQ_NONE)
+            req_tie2 = jnp.where(new_stamp, tie_now, state.req_tie)
+            tie_blk = 32767 - req_tie2
+            tie_idx = 32767 - jnp.arange(P, dtype=jnp.int32)
+            # per-policy cohort order (ArrayPolicy.fifo_tie): the array LRU
+            # tracks the engine best with the stream-block order; estimate-
+            # driven policies with the plan-deterministic index order (their
+            # scores already absorb the timing noise)
+            tie_tab = [tie_idx if p.fifo_tie == "plan" else tie_blk
+                       for p in policies]
+            if n_pol == 1:
+                tie15 = tie_tab[0]
+            else:
+                tie15 = jnp.stack(tie_tab)[pol_local]
+            load_key = jnp.where(wanted, stamp_age * 32768 + tie15, -1)
+
+        # ================= I/O server: budgeted admission =================
+        with phases.phase("io"):
+            used = jnp.sum(page_size * state.resident)
+            free = cfg.capacity_bytes - used
+            # engine parity: a running scan pins the pages of its current CPU
+            # burst — the last ~segment_pages plan entries behind the cursor —
+            # for the burst's duration; a blocked scan pins nothing, so pools
+            # far below streams x columns pages cannot livelock.  The array
+            # analogue pins pages whose trigger lies within a segment length
+            # (segment_pages plan entries ~= seg/dens tuples) behind the cursor
+            # of a stream that advanced this step.
+            seg_len = _SEG_PAGES / jnp.maximum(dens[:, 0], 1e-30)  # (S,) tuples
+            bk = jnp.arange(_SEG_WIN)                           # (B,)
+            b_local = frontier2[:, :, None] - 1 - bk[None, None, :]
+            b_pidx = col_start[None, :, None] + jnp.clip(
+                b_local, 0, col_npages[None, :, None] - 1
+            )
+            b_trig = jnp.maximum(page_first[b_pidx], start2[:, None, None])
+            burst = (
+                (b_local >= 0)
+                & (cols2 & active2[:, None])[:, :, None]
+                & runnable[:, None, None]
+                & (b_trig >= (cur2 - seg_len)[:, None, None])
+            )
+            pin = jnp.zeros(P, jnp.int32).at[b_pidx].max(burst.astype(jnp.int32))
+            evictable_io = state.resident & (pin == 0) & page_valid
+
+            # ============ cooperative I/O model (compiled on demand) ==========
+            if has_coop:
+                done3 = coop_mod.clear_on_query_change(
+                    coop_cpu.done, coop_cpu.finished
+                )
+                q_tab2 = q_table[s_idx, jnp.clip(qidx2, 0, Q - 1)]
+                coop_io = coop_mod.io_phase(
+                    cc, done=done3, cur_chunk=coop_cpu.cur_chunk,
+                    inflight=cstate.inflight, pin_pages=coop_cpu.pin_pages,
+                    active=active2, start=start2, end=end2, cols=cols2,
+                    q_tab=q_tab2, resident=state.resident, free=free,
+                    page_chunk_sizes=page_size, page_col=page_col,
+                    page_valid=page_valid, n_streams=S,
+                )
+                load_key = _sel(is_coop, coop_io.load_key, load_key)
+                wanted = _sel(is_coop, coop_io.wanted, wanted)
+                evictable = _sel(is_coop, coop_io.evictable, evictable_io)
+            else:
+                evictable = evictable_io
+            evictable_bytes = jnp.sum(page_size * evictable)
+            headroom = free + evictable_bytes
+            credit = state.io_credit + cfg.bandwidth * dt
+
+            # an invalid lane's server grants nothing (ok_id freeze)
+            budget = jnp.where(ok_id, jnp.minimum(credit, headroom), 0.0)
+            if horizon:
+                # serial-server causality over a macro-step: credit accrued
+                # while the queue was EMPTY must not fund requests that only
+                # appear at the end of the jump (the engine's idle server
+                # banks about one fine step of work, no more).  Cap this
+                # step's serviceable bytes at the queue content present when
+                # the jump began plus one fine step's credit — which also
+                # makes the cap vacuous at h_u == 1 (fixed-stepper parity).
+                # The queue bytes were computed by the previous step's
+                # horizon (they ARE its io-credit candidate) and carried.
+                pend_bytes0 = pend_in
+                if has_coop:
+                    infl0 = cstate.inflight
+                    pend_c0 = (
+                        (cc.page_chunk == jnp.clip(infl0, 0, cc.n_chunks - 1))
+                        & (infl0 >= 0) & ~state.resident & page_valid
+                    )
+                    pend_bytes0 = _sel(
+                        is_coop, jnp.sum(page_size * pend_c0), pend_bytes0
+                    )
+                budget = jnp.minimum(
+                    budget,
+                    state.io_credit + pend_bytes0 + cfg.bandwidth * dt_ref,
+                )
+                # budgeted FIFO pop as ONE batched grant op — the macro grant
+                # covers an h_io-fine-step jump without n_rounds serial
+                # argmax passes over the page axis (Pallas MXU prefix kernel
+                # on TPU, top_k + prefix-product oracle elsewhere).
+                # Semantics match the fixed loop: strict head-of-line (the
+                # first page that does not fit blocks the rest), ties by
+                # lower page index, _LOAD_MAX pops per fine step stood in
+                # for — all inside the static n_rounds top-k window, which
+                # therefore also caps a multi-step grant's pop count (the
+                # byte budget of an h_io-step jump fits the window at the
+                # validated operating points; credit a short window leaves
+                # unspent banks for the next step, like the fixed path's
+                # leftover credit).
+                if wake_exact:
+                    # non-saturated lanes keep the PR-9 pop cap bit-for-bit;
+                    # a wake-exact supersaturated jump needs every pop its
+                    # fine steps would have taken
+                    pop_cap = jnp.where(sat, n_rounds, n_rounds_io)
+                else:
+                    pop_cap = n_rounds
+                pops = jnp.minimum(h_u * _LOAD_MAX, pop_cap)
+                load_mask, load_bytes, n_load = kops.fifo_grant(
+                    load_key, page_size, budget, pops, vmax=n_rounds,
+                    page_axis=page_axis,
+                )
+                cand = cand_ok = None
+            else:
+                # the server grants at most ~credit bytes (a handful of pages)
+                # per step: pop the FIFO head a few times instead of sorting
+                # anything.  Head-of-line semantics: the first page that does
+                # not fit blocks the rest of the queue, like the engine's
+                # serial server.
+                kcur = load_key
+                taken = jnp.float32(0.0)
+                open_ = jnp.bool_(True)
+                arange_p = jnp.arange(P)
+                hit = jnp.zeros(P, bool)
+                cand = []
+                cand_ok = []
+                for _ in range(n_rounds):
+                    j = jnp.argmax(kcur)
+                    ok_j = open_ & (kcur[j] >= 0) & (
+                        taken + page_size[j] <= budget
+                    )
+                    open_ = ok_j
+                    is_j = arange_p == j   # arithmetic mask: fuses, scatter won't
+                    hit = hit | (is_j & ok_j)
+                    taken = taken + jnp.where(ok_j, page_size[j], 0.0)
+                    kcur = jnp.where(is_j, -1, kcur)
+                    cand.append(j)
+                    cand_ok.append(ok_j)
+                load_mask = hit
+                cand = jnp.stack(cand)                     # (n_rounds,)
+                cand_ok = jnp.stack(cand_ok)
+                load_bytes = taken
+                n_load = jnp.sum(cand_ok)
+
+            # bank leftover credit instead of zeroing it whenever the request
+            # queue went momentarily empty — that dropped the partially-funded
+            # head-of-line load and made effective bandwidth dip below
+            # cfg.bandwidth on bursty workloads.  The cap stays at 4 pages
+            # while requests remain unserved (funding the next grants); with an
+            # empty queue one page-time is kept, compensating the idle server's
+            # ability to start a load the instant a request arrives mid-step
+            # (the engine's serial server never banks more idle time than that).
+            leftover = credit - load_bytes
+            starved_io = jnp.sum(wanted & ~load_mask) > 0
+            credit_cap = jnp.where(starved_io, 4 * max_page, max_page)
+            io_credit2 = jnp.minimum(leftover, credit_cap)
+
+        # ================= policy hooks + batched eviction ================
+        with phases.phase("evict"):
+            # engine speed-estimate DIPS: the dict engine's per-burst EWMA
+            # crashes toward the effective rate at every stall exit, and pages
+            # pushed during a dip land in far-future buckets — prime eviction
+            # victims although their consumption is imminent.  That mis-push
+            # churn (7% of engine loads at 40% buffer, ~20% at 10%) never
+            # happens with a smooth estimate, leaving the array faster than
+            # the machine it models.  Sample the dips per (stream, step).
+            ud = _u01(jnp.arange(S, dtype=jnp.uint32), state.steps, 3266489917)
+            eff_rate = jnp.clip(
+                state.consumed / jnp.maximum(state.t, 1e-9),
+                1.0, None,
+            )
+            dip_p = jnp.float32(_DIP_P)
+            if horizon:
+                # the dip is a per-FINE-step Bernoulli (calibrated against
+                # the engine's stall-exit EWMA crashes): a macro-step
+                # standing in for h_u fine steps fires it with the
+                # compounded probability, like the request gate above —
+                # h_u == 1 keeps _DIP_P exactly (fixed-stepper bit parity).
+                # Without this the wake-exact path under-samples dips and
+                # ran ~18% too fast at the 10% deep-thrash point.
+                dip_p = jnp.where(
+                    h_u == 1, dip_p,
+                    1.0 - (1.0 - dip_p) ** h_u.astype(jnp.float32),
+                )
+            speed_push = jnp.where(
+                ud < dip_p, jnp.minimum(_DIP_DEPTH * eff_rate, speed2), speed2
+            )
+
+            # pages whose consumption state changed this step (feeds the churn
+            # diagnostic below and PBM's within-slice update set)
+            was_crossed = jnp.zeros(P, bool).at[cross_pidx].max(crossed)
+            if has_coop:
+                was_crossed = _sel(is_coop, coop_cpu.consumed_pages,
+                                   was_crossed)
+            if horizon:
+                # compacted within-slice update set: the padded (S, C, W)
+                # cross window grows with the horizon's longer trigger
+                # lookahead, but the pages that actually changed stay few —
+                # hand the policies a dense id list instead of the padded
+                # window (duplicates and the fill id carry ``upd_on`` False
+                # or an identical update value, so the min-combining scatter
+                # is unchanged; overflow beyond the static cap merely leaves
+                # a page's bucket stale until the slice refresh).
+                upd_mask = (was_crossed | load_mask) & page_valid
+                upd_pages = jnp.nonzero(upd_mask, size=min(P, 512),
+                                        fill_value=0)[0]
+                upd_on = upd_mask[upd_pages]
+            else:
+                upd_pages = upd_on = None
+            ctx = StepCtx(
+                spec=spec, refresh=refresh, time_slice=time_slice_f, now=t2,
+                steps=state.steps, slices_done=state.slices_done,
+                slices_elapsed=slices_u, dt=dt,
+                page_first=page_first, page_last=page_last, page_col=page_col,
+                page_valid=page_valid, resident=state.resident,
+                last_used=last_used2, load_mask=load_mask, load_cand=cand,
+                load_ok=cand_ok, cross_pidx=cross_pidx, crossed=crossed,
+                upd_pages=upd_pages, upd_on=upd_on,
+                active=active2, cols=cols2, cur=cur2, end=end2, start=start2,
+                eps=eps2, rate=rate2, speed_push=speed_push,
+                coop=coop_io if has_coop else None,
+            )
+            pstate2 = []
+            for p, ps in zip(policies, state.pstate):
+                if p.cooperative:
+                    # the cooperative substrate owns its state transitions
+                    pstate2.append(coop_mod.CoopState(
+                        done=done3, cur_chunk=coop_cpu.cur_chunk,
+                        chunk_pos=coop_cpu.chunk_pos, credit=coop_cpu.credit,
+                        inflight=coop_io.inflight,
+                    ))
+                else:
+                    pstate2.append(p.on_consume(p.on_request(ps, ctx), ctx))
+            keys = [p.score_victims(ps, ctx)
+                    for p, ps in zip(policies, pstate2)]
+            if n_pol == 1:
+                key = keys[0]
+            else:
+                key = jnp.stack(keys)[pol_local]
+
+            if refresh:
+                # query-end request drop, slice-quantised: pending requests for
+                # pages no active scan is interested in leave the queue
+                interested = (ctx.eta_estimate() < BIG_CUT) & page_valid
+                req_step2 = jnp.where(interested, req_step2, _REQ_NONE)
+                slices_done2 = state.slices_done + (
+                    jnp.int32(1) if slices_u is None else slices_u
+                )
+            else:
+                slices_done2 = state.slices_done
+
+            # engine parity: evictions are amortised in batches (>= 16 pages),
+            # so a triggered eviction frees up to a whole batch, not one page.
+            # The cooperative server instead evicts exactly the victims its
+            # chunk needs (ABM plans evictions per load decision).
+            batch = jnp.minimum(16 * max_page, cfg.capacity_bytes)
+            need_io = jnp.where(
+                load_bytes > free,
+                jnp.minimum(jnp.maximum(load_bytes, batch) - free,
+                            evictable_bytes),
+                0.0,
+            )
+            if has_coop:
+                need_coop = jnp.where(
+                    load_bytes > free,
+                    jnp.minimum(load_bytes - free, evictable_bytes),
+                    0.0,
+                )
+                need_free = _sel(is_coop, need_coop, need_io)
+            else:
+                need_free = need_io
+            evict = kops.batched_evict(key, page_size, evictable, need_free,
+                                       vmax=vmax, page_axis=page_axis)
+
+            resident2 = (state.resident & ~evict) | load_mask
+            last_used3 = jnp.where(load_mask, t2 + jit_p, last_used2)
+            # churn diagnostic: a page evicted while still "fresh" (loaded but
+            # never consumed since) was a wasted load
+            fresh2 = jnp.where(load_mask, True,
+                               state.fresh & ~was_crossed & resident2)
+            churn2 = state.churn + jnp.sum(state.fresh & evict & ~was_crossed)
+            req_step3 = jnp.where(load_mask, _REQ_NONE, req_step2)
+            demand_hit = load_mask & (bonus == 31)
+            if has_coop:
+                demand_hit = demand_hit & jnp.logical_not(is_coop)
+
+            new_state = SimState(
+                resident=resident2,
+                last_used=last_used3,
+                req_step=req_step3,
+                req_tie=req_tie2,
+                fresh=fresh2,
+                qidx=qidx2,
+                pos=pos2,
+                speed=speed2,
+                consumed=consumed2,
+                consumed_ref=consumed_ref2,
+                stream_done_t=stream_done_t2,
+                t=t2,
+                steps=state.steps + 1,
+                slices_done=slices_done2,
+                io_credit=io_credit2,
+                io_bytes=state.io_bytes + load_bytes,
+                loads=state.loads + n_load,
+                loads_demand=state.loads_demand + jnp.sum(demand_hit),
+                churn=churn2,
+                pstate=tuple(pstate2),
+            )
 
         # ============ obs tier 1: jit-pure carried counters ===============
         # (repro.obs, DESIGN.md §8).  Every source below is a value the
@@ -1173,172 +1184,174 @@ def make_step(spec: SimSpec, dt: float, time_slice: float,
         if tele is None:
             tele2 = None
         else:
-            hits_ev = jnp.sum(crossed)
-            if has_coop:
-                hits_ev = hits_ev + jnp.sum(touched_coop)
-                picks = coop_mod.chunk_pick(
-                    cstate.inflight, coop_io.inflight
-                ) & is_coop
-            else:
-                picks = jnp.bool_(False)
-            depth = jnp.sum((req_step3 != _REQ_NONE) & page_valid)
-            # victim rank in the policy's score order (0 = top victim):
-            # double argsort of the masked score, the rank histogram's
-            # high bins = the kernel digging past the policy preference
-            vrank = jnp.argsort(jnp.argsort(
-                -jnp.where(evictable, key, -INF)
-            ))
-            pol_rows = []
-            for j, (p, ps) in enumerate(zip(policies, pstate2)):
-                row = tele.pol_obs[j]
-                if row.shape[0]:
-                    o = p.observe(ps, ctx)
-                    if n_pol > 1:
-                        o = jnp.where(pol_local == j, o, 0.0)
-                    row = row + o
-                pol_rows.append(row)
-            tele2 = tele._replace(
-                hits=obs.count(tele.hits, hits_ev),
-                misses=obs.count(tele.misses, demand_hit),
-                loads=obs.count(tele.loads, n_load),
-                evictions=obs.count(tele.evictions, evict),
-                evict_rank=obs.hist(tele.evict_rank,
-                                    obs.log2_bin(vrank + 1), evict),
-                jump_hist=obs.hist(tele.jump_hist, obs.log2_bin(h_u), 1),
-                ioq_depth_sum=obs.count(tele.ioq_depth_sum, depth),
-                ioq_depth_max=jnp.maximum(tele.ioq_depth_max, depth),
-                chunk_picks=obs.count(tele.chunk_picks, picks),
-                pol_obs=tuple(pol_rows),
-            )
+            with phases.phase("obs"):
+                hits_ev = jnp.sum(crossed)
+                if has_coop:
+                    hits_ev = hits_ev + jnp.sum(touched_coop)
+                    picks = coop_mod.chunk_pick(
+                        cstate.inflight, coop_io.inflight
+                    ) & is_coop
+                else:
+                    picks = jnp.bool_(False)
+                depth = jnp.sum((req_step3 != _REQ_NONE) & page_valid)
+                # victim rank in the policy's score order (0 = top victim):
+                # double argsort of the masked score, the rank histogram's
+                # high bins = the kernel digging past the policy preference
+                vrank = jnp.argsort(jnp.argsort(
+                    -jnp.where(evictable, key, -INF)
+                ))
+                pol_rows = []
+                for j, (p, ps) in enumerate(zip(policies, pstate2)):
+                    row = tele.pol_obs[j]
+                    if row.shape[0]:
+                        o = p.observe(ps, ctx)
+                        if n_pol > 1:
+                            o = jnp.where(pol_local == j, o, 0.0)
+                        row = row + o
+                    pol_rows.append(row)
+                tele2 = tele._replace(
+                    hits=obs.count(tele.hits, hits_ev),
+                    misses=obs.count(tele.misses, demand_hit),
+                    loads=obs.count(tele.loads, n_load),
+                    evictions=obs.count(tele.evictions, evict),
+                    evict_rank=obs.hist(tele.evict_rank,
+                                        obs.log2_bin(vrank + 1), evict),
+                    jump_hist=obs.hist(tele.jump_hist, obs.log2_bin(h_u), 1),
+                    ioq_depth_sum=obs.count(tele.ioq_depth_sum, depth),
+                    ioq_depth_max=jnp.maximum(tele.ioq_depth_max, depth),
+                    chunk_picks=obs.count(tele.chunk_picks, picks),
+                    pol_obs=tuple(pol_rows),
+                )
 
         if not horizon:
             return new_state, view2, None, tele2
 
         # ================= event horizon of the NEXT step =================
-        # The earliest "interesting" time ahead, from the same machinery
-        # the policies already expose: the post-advance trigger window
-        # (computed here ONCE and carried — it is the next step's view
-        # window), the pending request queue, the cooperative chunk state,
-        # and the per-policy scan_horizon hooks.  Everything is a lower
-        # bound on "nothing the discretisation cares about happens before
-        # then"; overshoot is impossible because the CPU advance clamps at
-        # the first absent trigger and the refresh cadence is capped by
-        # the slice remainder in the runner's loop nest.
-        win2 = window(view2)
-        adv_lim2 = adv_limit(win2, resident2)
-        runnable2 = active2 & (adv_lim2 > 0.0)
-        remaining2 = jnp.maximum(_l2 - pos2, 0.0)
-        # next trigger arrival / stream completion: how long each runnable
-        # scan can burn CPU before it blocks, finishes, or outruns the
-        # window cap (rate without the per-query jitter: an 8% overshoot
-        # only means the scan blocks slightly before the jump ends)
-        t_cpu = jnp.where(
-            runnable2,
-            jnp.minimum(adv_lim2, remaining2) / jnp.maximum(rate2, 1.0),
-            INF,
-        )
-        # io-credit horizon: while requests are pending the server is the
-        # clock.  Non-saturated lanes jump at most h_io page-transfer
-        # times at the lane's own bandwidth (the wake-quantisation knob;
-        # blocked scans wake at jump end instead of mid-jump).
-        # SUPERSATURATED lanes — pool below the scans' aggregate plan
-        # window (streams x readahead entries), the engine's churn-spiral
-        # regime — used to keep the fine cadence entirely.  Under
-        # ``wake_exact`` they instead jump by the EXACT serial-server
-        # wake: with the queue frozen at this step's end the server's
-        # future is deterministic (each fine step banks bandwidth*dt_ref
-        # more credit and pops at most _LOAD_MAX fitting heads), so each
-        # queued page's grant step has a closed form (kernels.ops
-        # wake_solve, DESIGN.md §10) and the lane jumps straight to the
-        # first fine step that unblocks a stream — the dominant residual
-        # cost at deep thrash was exactly these h=1 crawl steps.
-        wanted3 = (req_step3 != _REQ_NONE) & ~resident2 & page_valid
-        pend_bytes2 = jnp.sum(jnp.where(wanted3, page_size, 0.0))
-        pend2 = pend_bytes2 > 0.0
-        t_io_base = h_io_f * dt_ref
-        if wake_exact:
-            # the queue key the NEXT step will serve: same stamp-FIFO
-            # construction as load_key, one step older (stamps are
-            # carried, ties were fixed at stamp time — uniform aging
-            # keeps the service order; later arrivals rank behind every
-            # frozen entry, so the predicted prefix is exact)
-            stamp_age3 = jnp.clip(state.steps + 2 - req_step3, 0, 32767)
-            wake_key = jnp.where(wanted3, stamp_age3 * 32768 + tie15, -1)
-            wake_step = kops.wake_solve(
-                wake_key, page_size, io_credit2,
-                cfg.bandwidth * dt_ref, jnp.int32(_LOAD_MAX),
-                h_cap=wake_cap_i,
+        with phases.phase("horizon"):
+            # The earliest "interesting" time ahead, from the same machinery
+            # the policies already expose: the post-advance trigger window
+            # (computed here ONCE and carried — it is the next step's view
+            # window), the pending request queue, the cooperative chunk state,
+            # and the per-policy scan_horizon hooks.  Everything is a lower
+            # bound on "nothing the discretisation cares about happens before
+            # then"; overshoot is impossible because the CPU advance clamps at
+            # the first absent trigger and the refresh cadence is capped by
+            # the slice remainder in the runner's loop nest.
+            win2 = window(view2)
+            adv_lim2 = adv_limit(win2, resident2)
+            runnable2 = active2 & (adv_lim2 > 0.0)
+            remaining2 = jnp.maximum(_l2 - pos2, 0.0)
+            # next trigger arrival / stream completion: how long each runnable
+            # scan can burn CPU before it blocks, finishes, or outruns the
+            # window cap (rate without the per-query jitter: an 8% overshoot
+            # only means the scan blocks slightly before the jump ends)
+            t_cpu = jnp.where(
+                runnable2,
+                jnp.minimum(adv_lim2, remaining2) / jnp.maximum(rate2, 1.0),
+                INF,
             )
-            # a blocked stream wakes when EVERY absent page it sits on
-            # (trigger at/behind the cursor, all columns) is granted:
-            # per-stream max over those pages' grant steps, then the
-            # lane jumps to the EARLIEST such wake
-            w_pidx2, _wt2, w_need2, w_dist2 = win2
-            absent2 = w_need2[:, :, :W] & ~resident2[w_pidx2[:, :, :W]]
-            d0 = absent2 & (w_dist2[:, :, :W] <= 0.0)
-            kp = jnp.where(
-                d0, wake_step[w_pidx2[:, :, :W]].astype(jnp.float32), 0.0
+            # io-credit horizon: while requests are pending the server is the
+            # clock.  Non-saturated lanes jump at most h_io page-transfer
+            # times at the lane's own bandwidth (the wake-quantisation knob;
+            # blocked scans wake at jump end instead of mid-jump).
+            # SUPERSATURATED lanes — pool below the scans' aggregate plan
+            # window (streams x readahead entries), the engine's churn-spiral
+            # regime — used to keep the fine cadence entirely.  Under
+            # ``wake_exact`` they instead jump by the EXACT serial-server
+            # wake: with the queue frozen at this step's end the server's
+            # future is deterministic (each fine step banks bandwidth*dt_ref
+            # more credit and pops at most _LOAD_MAX fitting heads), so each
+            # queued page's grant step has a closed form (kernels.ops
+            # wake_solve, DESIGN.md §10) and the lane jumps straight to the
+            # first fine step that unblocks a stream — the dominant residual
+            # cost at deep thrash was exactly these h=1 crawl steps.
+            wanted3 = (req_step3 != _REQ_NONE) & ~resident2 & page_valid
+            pend_bytes2 = jnp.sum(jnp.where(wanted3, page_size, 0.0))
+            pend2 = pend_bytes2 > 0.0
+            t_io_base = h_io_f * dt_ref
+            if wake_exact:
+                # the queue key the NEXT step will serve: same stamp-FIFO
+                # construction as load_key, one step older (stamps are
+                # carried, ties were fixed at stamp time — uniform aging
+                # keeps the service order; later arrivals rank behind every
+                # frozen entry, so the predicted prefix is exact)
+                stamp_age3 = jnp.clip(state.steps + 2 - req_step3, 0, 32767)
+                wake_key = jnp.where(wanted3, stamp_age3 * 32768 + tie15, -1)
+                wake_step = kops.wake_solve(
+                    wake_key, page_size, io_credit2,
+                    cfg.bandwidth * dt_ref, jnp.int32(_LOAD_MAX),
+                    h_cap=wake_cap_i,
+                )
+                # a blocked stream wakes when EVERY absent page it sits on
+                # (trigger at/behind the cursor, all columns) is granted:
+                # per-stream max over those pages' grant steps, then the
+                # lane jumps to the EARLIEST such wake
+                w_pidx2, _wt2, w_need2, w_dist2 = win2
+                absent2 = w_need2[:, :, :W] & ~resident2[w_pidx2[:, :, :W]]
+                d0 = absent2 & (w_dist2[:, :, :W] <= 0.0)
+                kp = jnp.where(
+                    d0, wake_step[w_pidx2[:, :, :W]].astype(jnp.float32), 0.0
+                )
+                k_stream = jnp.max(kp, axis=(1, 2))
+                blocked_s = active2 & ~runnable2 & jnp.any(d0, axis=(1, 2))
+                k_wake = jnp.min(jnp.where(blocked_s, k_stream, INF))
+                # headroom guard: the solve's credit cadence is only real
+                # while the pool (free + evictable bytes) can absorb it —
+                # past that the budget pins at headroom and the schedule is
+                # no longer a lower bound; fall back to the fine cadence.
+                # No blocked stream at all: the CPU candidates own the
+                # horizon, quantised like a non-saturated pending jump.
+                can_jump = jnp.isfinite(k_wake) & (
+                    io_credit2 + k_wake * cfg.bandwidth * dt_ref <= headroom
+                )
+                t_wake = jnp.where(
+                    can_jump, (k_wake + 0.25) * dt_ref,
+                    jnp.where(jnp.isfinite(k_wake), dt_ref, t_io_base),
+                )
+                t_io_pend = jnp.where(sat, t_wake, t_io_base)
+            else:
+                t_io_pend = jnp.where(sat, 0.0, t_io_base)
+            t_io = jnp.where(pend2, t_io_pend, INF)
+            if has_coop:
+                # cooperative lanes: the in-order trigger candidate is
+                # meaningless (consumption is chunk-granular, out of order);
+                # the chunk in flight plays the pending queue's role.  The
+                # wake solve models the in-order stamp queue, not chunks —
+                # cooperative lanes keep the pre-wake-exact candidates.
+                t_cpu = _sel(is_coop, jnp.full(S, INF), t_cpu)
+                t_io_coop = (jnp.where(sat, 0.0, t_io_base) if wake_exact
+                             else t_io_pend)
+                t_io = _sel(
+                    is_coop,
+                    jnp.where(coop_io.inflight >= 0, t_io_coop, INF),
+                    t_io,
+                )
+            # per-policy horizon providers (ArrayPolicy.scan_horizon): e.g.
+            # array-CScan reports each stream's current-chunk completion
+            hz = HorizonView(spec=spec, active=active2, start=start2, end=end2,
+                             rate=rate2, dt_ref=dt_ref)
+            t_tab = [p.scan_horizon(ps, hz) for p, ps in zip(policies, pstate2)]
+            if any(t is not None for t in t_tab):
+                t_tab = [jnp.full(S, INF) if t is None else t for t in t_tab]
+                t_pol = t_tab[0] if n_pol == 1 else \
+                    jnp.stack(t_tab)[pol_local]
+                t_pol_min = jnp.min(t_pol)
+            else:
+                t_pol_min = INF
+            next_dt = jnp.minimum(jnp.minimum(jnp.min(t_cpu), t_io), t_pol_min)
+            # quantise to whole fine steps (floor: undershooting a horizon
+            # only costs an extra step; overshooting would cost fidelity).
+            # Wake-exact supersaturated lanes may plan past h_max up to the
+            # wake cap — the slice loop still clips each macro-step at the
+            # boundary, and the refresh step absorbs whole slices from the
+            # surplus (_MAX_ABSORB at most).
+            if wake_exact:
+                h_cap_lane = jnp.where(sat, wake_cap_i, h_max_i)
+            else:
+                h_cap_lane = h_max_i
+            next_h = jnp.clip(
+                jnp.floor(next_dt / dt_ref).astype(jnp.int32), 1, h_cap_lane
             )
-            k_stream = jnp.max(kp, axis=(1, 2))
-            blocked_s = active2 & ~runnable2 & jnp.any(d0, axis=(1, 2))
-            k_wake = jnp.min(jnp.where(blocked_s, k_stream, INF))
-            # headroom guard: the solve's credit cadence is only real
-            # while the pool (free + evictable bytes) can absorb it —
-            # past that the budget pins at headroom and the schedule is
-            # no longer a lower bound; fall back to the fine cadence.
-            # No blocked stream at all: the CPU candidates own the
-            # horizon, quantised like a non-saturated pending jump.
-            can_jump = jnp.isfinite(k_wake) & (
-                io_credit2 + k_wake * cfg.bandwidth * dt_ref <= headroom
-            )
-            t_wake = jnp.where(
-                can_jump, (k_wake + 0.25) * dt_ref,
-                jnp.where(jnp.isfinite(k_wake), dt_ref, t_io_base),
-            )
-            t_io_pend = jnp.where(sat, t_wake, t_io_base)
-        else:
-            t_io_pend = jnp.where(sat, 0.0, t_io_base)
-        t_io = jnp.where(pend2, t_io_pend, INF)
-        if has_coop:
-            # cooperative lanes: the in-order trigger candidate is
-            # meaningless (consumption is chunk-granular, out of order);
-            # the chunk in flight plays the pending queue's role.  The
-            # wake solve models the in-order stamp queue, not chunks —
-            # cooperative lanes keep the pre-wake-exact candidates.
-            t_cpu = _sel(is_coop, jnp.full(S, INF), t_cpu)
-            t_io_coop = (jnp.where(sat, 0.0, t_io_base) if wake_exact
-                         else t_io_pend)
-            t_io = _sel(
-                is_coop,
-                jnp.where(coop_io.inflight >= 0, t_io_coop, INF),
-                t_io,
-            )
-        # per-policy horizon providers (ArrayPolicy.scan_horizon): e.g.
-        # array-CScan reports each stream's current-chunk completion
-        hz = HorizonView(spec=spec, active=active2, start=start2, end=end2,
-                         rate=rate2, dt_ref=dt_ref)
-        t_tab = [p.scan_horizon(ps, hz) for p, ps in zip(policies, pstate2)]
-        if any(t is not None for t in t_tab):
-            t_tab = [jnp.full(S, INF) if t is None else t for t in t_tab]
-            t_pol = t_tab[0] if n_pol == 1 else \
-                jnp.stack(t_tab)[pol_local]
-            t_pol_min = jnp.min(t_pol)
-        else:
-            t_pol_min = INF
-        next_dt = jnp.minimum(jnp.minimum(jnp.min(t_cpu), t_io), t_pol_min)
-        # quantise to whole fine steps (floor: undershooting a horizon
-        # only costs an extra step; overshooting would cost fidelity).
-        # Wake-exact supersaturated lanes may plan past h_max up to the
-        # wake cap — the slice loop still clips each macro-step at the
-        # boundary, and the refresh step absorbs whole slices from the
-        # surplus (_MAX_ABSORB at most).
-        if wake_exact:
-            h_cap_lane = jnp.where(sat, wake_cap_i, h_max_i)
-        else:
-            h_cap_lane = h_max_i
-        next_h = jnp.clip(
-            jnp.floor(next_dt / dt_ref).astype(jnp.int32), 1, h_cap_lane
-        )
         return new_state, view2, (win2, adv_lim2, pend_bytes2, next_h), tele2
 
     # telemetry rides at the END of every carry so the loop conditions'
@@ -1621,7 +1634,11 @@ def make_runner(
 
     def counted_run(cfg: ArraySimConfig):
         trace_counter["n"] += 1
-        return run(cfg)
+        # the loop nest's own ops (conditions, carry threading between the
+        # refresh and within-slice steps) are the ``loop`` phase; the
+        # step's sections open their phases inside it
+        with phases.phase("loop"):
+            return run(cfg)
 
     if mesh is not None:
         # configs shard over the lane axis only; per-page state is

@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import ref
 from .flash_attention import flash_attention_kernel
 from .mamba2_scan import mamba2_scan_kernel
@@ -185,10 +186,11 @@ def fifo_grant(key, sizes, budget, pops, *, vmax: int = 16,
     prefix solve runs on the gathered compact list — bitwise-identical
     to the unsharded path (see the sharding note above).
 
-    The ``jax.named_scope`` span names this op in profiler traces and in
-    lowered HLO, so ``benchmarks/roofline.py --kernels`` and a Perfetto
-    capture both attribute its cost to ``kernel:fifo_grant``."""
-    with jax.named_scope("kernel:fifo_grant"):
+    The ``kernel:fifo_grant`` scope (``repro.obs.scope``) names this op
+    in the compiled HLO's ``op_name`` metadata and in profiler captures,
+    inside the step phase that calls it; ``bench/trace.py`` attributes
+    device time to it and ``bench/kernel_work.py`` prices its calls."""
+    with obs.scope("kernel:fifo_grant"):
         if page_axis is not None:
             return _grant_page_sharded(key, sizes, budget, pops, vmax,
                                        page_axis)
@@ -219,10 +221,10 @@ def batched_evict(key, sizes, evictable, need_free, *, vmax: int = 64,
     bitwise-identical to the unsharded path (see the sharding note
     above).
 
-    Wrapped in a ``jax.named_scope`` span so profiler traces and
-    ``roofline.py --kernels`` attribute it as ``kernel:batched_evict``.
+    Scoped as ``kernel:batched_evict`` (``repro.obs.scope``), which
+    ``bench/trace.py`` and ``bench/kernel_work.py`` read.
     """
-    with jax.named_scope("kernel:batched_evict"):
+    with obs.scope("kernel:batched_evict"):
         if page_axis is not None:
             return _evict_page_sharded(key, sizes, evictable, need_free,
                                        vmax, page_axis)
@@ -251,9 +253,9 @@ def wake_solve(key, sizes, credit0, inc, pops, *, h_cap: int = 64):
     sharding the inputs are replicated and the solve's outputs feed
     lane-global jump decisions, so it runs replicated as-is.
 
-    Wrapped in a ``jax.named_scope`` span so profiler traces and
-    ``roofline.py --kernels`` attribute it as ``kernel:wake_solve``."""
-    with jax.named_scope("kernel:wake_solve"):
+    Scoped as ``kernel:wake_solve`` (``repro.obs.scope``), which
+    ``bench/trace.py`` and ``bench/kernel_work.py`` read."""
+    with obs.scope("kernel:wake_solve"):
         mode = _use_pallas()
         if mode is not False:
             return wake_solve_kernel(

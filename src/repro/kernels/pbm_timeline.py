@@ -337,6 +337,7 @@ def fifo_grant_kernel(
             pltpu.VMEM((1, _BLOCK), jnp.float32),  # blocked accumulator
         ],
         interpret=interpret,
+        name="kernel_fifo_grant",
     )(iscal, fscal, occ, key_row, key_row, sz_row, sz_row)
     mask = grant[0, :P] > 0
     granted = jnp.where(mask, sizes, 0.0)
@@ -391,6 +392,7 @@ def batched_evict_kernel(
             pltpu.VMEM((1, _BLOCK), jnp.float32),  # rank accumulator
         ],
         interpret=interpret,
+        name="kernel_batched_evict",
     )(fscal, occ, key_row, key_row, sz_row, ev_row, ev_row)
     return evict[0, :P] > 0
 
@@ -446,5 +448,6 @@ def wake_solve_kernel(
             pltpu.VMEM((h_cap, 1), jnp.float32),    # n_k
         ],
         interpret=interpret,
+        name="kernel_wake_solve",
     )(iscal, fscal, occ, key_row, key_row, sz_row, sz_row)
     return wake[0, :P]

@@ -13,10 +13,14 @@ The contracts under test, in acceptance order:
 * a ``jax.debug.print`` seeded into a policy hook is caught by the
   ``jit-host-callback`` lint rule, and ``# analysis: obs`` escapes it;
 * every RunManifest carries the attribution fields trend.py needs;
+* the named phases reach the compiled ``op_name`` metadata, tally their
+  own host seconds while JAX traces them, and the set-up spans record
+  in order;
 * ``ServingEngine`` structured events agree with ``EngineStats``.
 """
 
 import textwrap
+import time
 
 import numpy as np
 import pytest
@@ -34,7 +38,16 @@ from repro.core.workload import (
     micro_accessed_bytes,
     micro_streams,
 )
-from repro.obs import collect_manifest, counters, spec_hash
+from repro.obs import (
+    Record,
+    collect_manifest,
+    counters,
+    phase,
+    phase_of,
+    scope,
+    span,
+    spec_hash,
+)
 from repro.obs.trace import TraceSession, serving_events_to_chrome
 
 TRACED_REL = "repro/core/array_sim/policies.py"
@@ -238,6 +251,57 @@ def test_obs_counters_module_is_a_traced_region():
             return c
     """, rel="repro/obs/counters.py")
     assert "jit-host-callback" in rules
+
+
+# ------------------------------------------------------ phases and spans --
+
+def test_phase_scopes_reach_op_names_and_tally_trace_seconds():
+    import jax
+    import jax.numpy as jnp
+
+    def f(x):
+        with phase("io"):
+            y = x + 1.0
+            with scope("kernel:fifo_grant"):
+                time.sleep(0.02)
+                y = y * 2.0
+        return y
+
+    jitted = jax.jit(f)
+    x = jnp.ones(3)
+    with Record() as rec:
+        text = jitted.lower(x).compile().as_text()
+        jitted(x)                      # a cached call traces nothing
+    assert "step:io/kernel:fifo_grant" in text
+    assert set(rec.trace_seconds) == {"step:io", "kernel:fifo_grant"}
+    # each scope keeps its own seconds, not those of the scopes inside it
+    assert rec.trace_seconds["kernel:fifo_grant"] >= 0.02
+    assert rec.trace_seconds["step:io"] < 0.02
+    with Record() as later:
+        jitted(x)
+    assert later.trace_seconds == {}
+
+
+def test_phase_vocabulary():
+    with pytest.raises(ValueError, match="unknown step phase"):
+        phase("kernel")
+    assert phase_of("jit(timed)/step:loop/while/body/step:io/"
+                    "kernel:fifo_grant/pallas_call") == "step:io"
+    assert phase_of("jit(timed)/vmap(jit(counted_run))") is None
+
+
+def test_spans_record_in_order_while_a_record_is_open():
+    t0 = time.perf_counter()
+    with Record() as rec:
+        with span("setup:start", start=t0):
+            pass
+        with span("setup:warm"):
+            time.sleep(0.01)
+    with span("setup:load"):
+        pass
+    (n0, s0, e0), (n1, s1, e1) = rec.spans
+    assert (n0, n1) == ("setup:start", "setup:warm")
+    assert s0 == t0 <= e0 <= s1 and e1 - s1 >= 0.01
 
 
 # ------------------------------------------------- serving structured events --

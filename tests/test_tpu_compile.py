@@ -5,7 +5,9 @@ that the chip's compiler refuses still passes there.  These tests compile
 ``batched_evict`` (f32 and i32 keys), ``fifo_grant`` and ``wake_solve``
 for a described ``v5e:2x2`` topology, with nothing attached, at the SF30
 TPC-H pool (P = 8,832 pages) and at a ragged pool (3 blocks + 129), and
-check that the compiled program holds the Mosaic kernel.
+check that the compiled program holds the Mosaic kernel under the name
+its ``pallas_call`` pins (``kernel_<op>``), the name the benchmark's
+trace reduction matches.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so describing it while the
@@ -82,6 +84,7 @@ def test_batched_evict_compiles(one_chip, P, key_dtype):
         _spec(one_chip, (), jnp.float32),
     )
     assert "tpu_custom_call" in text
+    assert "%kernel_batched_evict." in text
 
 
 @pytest.mark.parametrize("P", POOLS)
@@ -94,6 +97,7 @@ def test_fifo_grant_compiles(one_chip, P):
         _spec(one_chip, (), jnp.int32),
     )
     assert "tpu_custom_call" in text
+    assert "%kernel_fifo_grant." in text
 
 
 @pytest.mark.parametrize("P", POOLS)
@@ -107,3 +111,4 @@ def test_wake_solve_compiles(one_chip, P):
         _spec(one_chip, (), jnp.int32),
     )
     assert "tpu_custom_call" in text
+    assert "%kernel_wake_solve." in text
