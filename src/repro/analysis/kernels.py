@@ -329,6 +329,9 @@ def _c_batched_evict_i32():
 
 
 def _c_fifo_grant():
+    """One grid step (``jax.vmap`` adds the lane axis) holding the whole
+    pool row in VMEM, dense as (P_pad / 128, 128): the recorder sees a
+    single full-row block per operand."""
     import jax.numpy as jnp
     from repro.kernels.pbm_timeline import fifo_grant_kernel
 
@@ -340,9 +343,8 @@ def _c_fifo_grant():
 
 
 def _c_fifo_grant_tail():
-    """Blocked geometry with a ragged tail: P = 3 x _BLOCK + 129 pads to
-    a fourth block, so the recorder sees the (phase, i, j) grid walking
-    partially-padded edge tiles — the coverage/bounds rules must hold
+    """The same geometry at a ragged pool: P = 3 x 512 + 129 pads to
+    2,048 pages, (16, 128), so the coverage and bounds rules must hold
     with padding in play, not just at the divisible example point."""
     import jax.numpy as jnp
     from repro.kernels.pbm_timeline import fifo_grant_kernel

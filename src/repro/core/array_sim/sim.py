@@ -954,7 +954,7 @@ def make_step(spec: SimSpec, dt: float, time_slice: float,
                 )
                 # budgeted FIFO pop as ONE batched grant op — the macro grant
                 # covers an h_io-fine-step jump without n_rounds serial
-                # argmax passes over the page axis (Pallas MXU prefix kernel
+                # argmax passes over the page axis (Pallas head-of-queue kernel
                 # on TPU, top_k + prefix-product oracle elsewhere).
                 # Semantics match the fixed loop: strict head-of-line (the
                 # first page that does not fit blocks the rest), ties by

@@ -15,28 +15,37 @@ selecting between their score arrays.
 Design notes
 ------------
 * All per-page state is dense ``(1, P)`` rows; wrappers pad P up to a
-  multiple of ``_BLOCK`` with exact sentinels (non-wanted key, zero
-  size, non-evictable) and slice the padding back off, so any P works
-  and every BlockSpec divides its operand.
-* Victim/grant selection is a prefix-sum over the priority order.
-  Instead of sorting (awkward on the VPU), we compute for every page
-  the bytes that would be freed/served *before* it via a masked
+  multiple of ``_BLOCK`` (the grant's to a multiple of ``_DENSE``) with
+  exact sentinels (non-wanted key, zero size, non-evictable) and slice
+  the padding back off, so any P works and every BlockSpec divides its
+  operand.
+* Victim selection and the wake solve are prefix sums over the priority
+  order.  Instead of sorting (awkward on the VPU), we compute for every
+  page the bytes that would be freed/served *before* it via a masked
   comparison tile contracted against page sizes on the MXU.
-* Since PR 10 the O(P^2) prefix work is **gridded over page blocks**:
-  grid ``(i, j)`` walks (row-block, col-block) tiles of the comparison
+* That O(P^2) prefix work is **gridded over page blocks**: grid
+  ``(i, j)`` walks (row-block, col-block) tiles of the comparison
   matrix with j innermost, accumulating per-row prefix bytes and ranks
   in VMEM scratch (reset at ``j == 0``, committed under
   ``pl.when(j == n_j - 1)`` — the sanctioned accumulator-revisit
   pattern).  Per-step VMEM is O(_BLOCK^2) regardless of P, so
   P >> VMEM satisfies the contract verifier's vmem-budget rule.
-  Passes that need a *global* intermediate (the grant kernel's strict
-  head-of-line ``fits`` vector, the wake kernel's per-page rank/prefix
-  bytes) run as an extra leading phase axis: TPU grids are sequential,
-  so phase 0 fully populates the (1, P_pad) scratch before phase 1
-  reads it.
+  The wake kernel's passes that need a *global* intermediate (per-page
+  rank/prefix bytes) run as an extra leading phase axis: TPU grids are
+  sequential, so phase 0 fully populates the (1, P_pad) scratch before
+  phase 1 reads it.
 * A tile whose row or column page block holds no candidate (queued or
   evictable page) adds exactly zero, so the kernels skip it under
   ``pl.when`` on a per-block occupancy row passed in SMEM.
+* The grant needs no prefix over the whole queue: it is a prefix of the
+  service order that stops at the first head that does not fit, and a
+  call grants a handful of pages.  So it runs one grid step per lane
+  (``jax.vmap`` adds the lane axis) over the lane's key and size rows
+  held whole in VMEM, dense as ``(P_pad / 128, 128)``, and pops heads —
+  a max over the keys, the lowest page index among the ties, a masked
+  sum for the size — until one is not wanted, exceeds the pop cap or
+  overruns the byte budget.  Its work is one vector pass per page
+  granted plus one; its VMEM is O(P): 24 bytes a page, double-buffered.
 
 Semantics are defined by the oracles in ``repro.kernels.ref``
 (``batched_evict_ref`` / ``fifo_grant_ref`` / ``wake_solve_ref``);
@@ -58,6 +67,12 @@ NEG_I32 = -(2**31) + 1  # i32 sentinel for the integer-key path
 #: page-block width of the gridded kernels — each grid step touches an
 #: O(_BLOCK^2) comparison tile, so VMEM stays bounded for any P
 _BLOCK = 512
+
+#: lanes of a vreg; the grant kernel lays a pool row out as (rows, 128),
+#: padded to a multiple of _DENSE pages so the rows fill whole (8, 128)
+#: vregs
+_LANES = 128
+_DENSE = 8 * _LANES
 
 
 def _blocks(P: int) -> tuple[int, int]:
@@ -147,72 +162,34 @@ def _kernel(fscal_ref, occ_ref, key_i_ref, key_j_ref, sizes_j_ref, ev_i_ref,
         evict_out_ref[...] = take.astype(jnp.float32)
 
 
-def _grant_kernel(iscal_ref, fscal_ref, occ_ref, key_i_ref, key_j_ref,
-                  sizes_i_ref, sizes_j_ref, grant_out_ref,
-                  fits_ref, bytes_acc_ref, rank_acc_ref, blk_acc_ref,
-                  *, vmax: int, block: int, n_j: int):
-    ph = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    pops = iscal_ref[0, 0]
+def _grant_kernel(iscal_ref, fscal_ref, key_ref, sizes_ref, grant_out_ref,
+                  *, vmax: int):
+    cap = jnp.minimum(iscal_ref[0, 0], vmax)
     budget = fscal_ref[0, 0]
-
-    wanted_p = key_i_ref[:] >= 0      # (1, block) i32 keys — the FIFO keys
-    wanted_q = key_j_ref[:] >= 0      # use up to ~30 bits (stamp*32768 +
+    key0 = key_ref[...]               # (rows, 128) i32 keys — the FIFO keys
+    sizes = sizes_ref[...]            # use up to ~30 bits (stamp*32768 +
                                       # tie), so an f32 cast would round
                                       # away the tie bits beyond 2^24
-    # a tile whose row or column block holds no queued page changes nothing
-    busy = (occ_ref[0, i] > 0) & (occ_ref[0, j] > 0)
+    idx = (jax.lax.broadcasted_iota(jnp.int32, key0.shape, 0) * _LANES
+           + jax.lax.broadcasted_iota(jnp.int32, key0.shape, 1))
+    p_pad = key0.size
 
-    def before():
-        return _order_before(key_i_ref[:].reshape(block, 1), key_j_ref[:],
-                             i, j, block) & wanted_q
+    def pop(carry):
+        # one head of the queue: the highest wanted key, ties by the lower
+        # page index; it is granted only if it fits, else it blocks the rest
+        _, n, nbytes, key = carry
+        top = jnp.max(key)
+        head = idx == jnp.min(jnp.where(key == top, idx, p_pad))
+        size = jnp.sum(jnp.where(head, sizes, 0.0))
+        fits = (top >= 0) & (n < cap) & (nbytes + size <= budget)
+        return (fits, n + fits.astype(jnp.int32),
+                jnp.where(fits, nbytes + size, nbytes),
+                jnp.where(head & fits, -1, key))   # a granted page leaves
 
-    # ---- phase 0: budget/pops feasibility per page ("fits") --------------
-    @pl.when((ph == 0) & (j == 0))
-    def _init_fits():
-        bytes_acc_ref[...] = jnp.zeros_like(bytes_acc_ref)
-        rank_acc_ref[...] = jnp.zeros_like(rank_acc_ref)
-
-    @pl.when((ph == 0) & busy)
-    def _acc_fits():
-        b = before()
-        sz = (sizes_j_ref[:] * wanted_q).reshape(block, 1)
-        bytes_acc_ref[...] = bytes_acc_ref[...] + _prefix_dot(
-            b, sz).reshape(1, block)
-        rank_acc_ref[...] = rank_acc_ref[...] + jnp.sum(
-            b, axis=1, dtype=jnp.float32,
-        ).reshape(1, block)
-
-    @pl.when((ph == 0) & (j == n_j - 1))
-    def _store_fits():
-        cap = jnp.minimum(pops, vmax).astype(jnp.float32)
-        fits = (
-            wanted_p
-            & (bytes_acc_ref[...] + sizes_i_ref[:] <= budget)
-            & (rank_acc_ref[...] < cap)
-        )
-        fits_ref[0, pl.ds(i * block, block)] = \
-            fits.astype(jnp.float32).reshape(block)
-
-    # ---- phase 1: strict head-of-line — a non-fitting wanted predecessor
-    # blocks every later pop, like the engine's serial server ---------------
-    @pl.when((ph == 1) & (j == 0))
-    def _init_blk():
-        blk_acc_ref[...] = jnp.zeros_like(blk_acc_ref)
-
-    @pl.when((ph == 1) & busy)
-    def _acc_blk():
-        fits_j = fits_ref[0, pl.ds(j * block, block)].reshape(1, block)
-        nonfit = (wanted_q & (fits_j == 0)).astype(jnp.float32)
-        blk_acc_ref[...] = blk_acc_ref[...] + _prefix_dot(
-            before(), nonfit.reshape(block, 1)).reshape(1, block)
-
-    @pl.when((ph == 1) & (j == n_j - 1))
-    def _commit():
-        fits_i = fits_ref[0, pl.ds(i * block, block)].reshape(1, block)
-        grant_out_ref[...] = \
-            ((fits_i > 0) & (blk_acc_ref[...] == 0)).astype(jnp.float32)
+    start = (cap > 0, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
+             key0)
+    key = jax.lax.while_loop(lambda carry: carry[0], pop, start)[3]
+    grant_out_ref[...] = (key != key0).astype(jnp.int32)
 
 
 def _wake_kernel(iscal_ref, fscal_ref, occ_ref, key_i_ref, key_j_ref,
@@ -304,42 +281,35 @@ def fifo_grant_kernel(
     vmax: int = 16,
     interpret: bool = False,
 ):
-    """Budgeted FIFO grant selection (the array sim's I/O server pop) as
-    a page-blocked MXU prefix computation (grid = (phase, i, j), phase 0
-    feasibility / phase 1 head-of-line).  Returns ``(grant_mask,
+    """Budgeted FIFO grant selection (the array sim's I/O server pop) by
+    head-of-queue extraction: one grid step holds the lane's key and size
+    rows whole in VMEM, laid out dense as ``(P_pad / 128, 128)``, and
+    pops heads until the first that does not fit, so a call does one
+    vector pass per page granted plus one.  Returns ``(grant_mask,
     granted_bytes, n_granted)``; semantics defined by
     ``ref.fifo_grant_ref`` (tests assert exact agreement in interpret
     mode)."""
     P = key.shape[0]
-    p_pad, n_b = _blocks(P)
-    key_row = _pad_row(key.reshape(1, P).astype(jnp.int32), p_pad, -1)
-    sz_row = _pad_row(sizes.reshape(1, P).astype(jnp.float32), p_pad, 0.0)
+    p_pad = -(-P // _DENSE) * _DENSE
+    rows = p_pad // _LANES
+    key_2d = _pad_row(key.reshape(1, P).astype(jnp.int32), p_pad,
+                      -1).reshape(rows, _LANES)
+    sz_2d = _pad_row(sizes.reshape(1, P).astype(jnp.float32), p_pad,
+                     0.0).reshape(rows, _LANES)
     iscal = jnp.asarray(pops, jnp.int32).reshape(1, 1)
     fscal = jnp.asarray(budget, jnp.float32).reshape(1, 1)
-    occ = _occupied(key_row >= 0, n_b)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    row_i = pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
-                         memory_space=pltpu.VMEM)
-    row_j = pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, j),
+    whole = pl.BlockSpec((rows, _LANES), lambda: (0, 0),
                          memory_space=pltpu.VMEM)
     grant = pl.pallas_call(
-        functools.partial(_grant_kernel, vmax=min(vmax, P), block=_BLOCK,
-                          n_j=n_b),
-        grid=(2, n_b, n_b),
-        out_shape=jax.ShapeDtypeStruct((1, p_pad), jnp.float32),
-        in_specs=[smem, smem, smem, row_i, row_j, row_i, row_j],
-        out_specs=pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((1, p_pad), jnp.float32),   # fits (global, phase 0->1)
-            pltpu.VMEM((1, _BLOCK), jnp.float32),  # prefix-bytes accumulator
-            pltpu.VMEM((1, _BLOCK), jnp.float32),  # rank accumulator
-            pltpu.VMEM((1, _BLOCK), jnp.float32),  # blocked accumulator
-        ],
+        functools.partial(_grant_kernel, vmax=min(vmax, P)),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+        in_specs=[smem, smem, whole, whole],
+        out_specs=whole,
         interpret=interpret,
         name="kernel_fifo_grant",
-    )(iscal, fscal, occ, key_row, key_row, sz_row, sz_row)
-    mask = grant[0, :P] > 0
+    )(iscal, fscal, key_2d, sz_2d)
+    mask = grant.reshape(p_pad)[:P] > 0
     granted = jnp.where(mask, sizes, 0.0)
     return mask, jnp.sum(granted), jnp.sum(mask)
 

@@ -23,6 +23,7 @@ property-based pass widens the shape coverage.
 
 from __future__ import annotations
 
+import functools
 import textwrap
 
 import numpy as np
@@ -368,8 +369,9 @@ def test_set_backend_accepts_known_names():
 # differential fuzz: interpret-mode kernels == oracles, EXACTLY
 # ---------------------------------------------------------------------------
 # Sizes are integer-valued f32 and keys stay within [-2^30, 2^30), so
-# every sum the kernels take (MXU prefix bytes vs the oracle's cumsum)
-# is exact in f32 — any mismatch is a real semantics bug, not rounding.
+# every sum the kernels take (MXU prefix bytes, the grant's running sum,
+# the oracle's cumsum) is exact in f32 — any mismatch is a real
+# semantics bug, not rounding.
 
 def _evict_case(rng, P, *, int_keys, all_ineligible=False, zero_need=False,
                 vmax=None):
@@ -476,6 +478,73 @@ def test_fifo_grant_edge_cases():
     _assert_grant_agrees(*_grant_case(rng, 64, zero_budget=True))
     _assert_grant_agrees(*_grant_case(rng, 64, none_wanted=True))
     _assert_grant_agrees(*_grant_case(rng, 1))
+
+
+#: the cell's pool: 3,712 pages, 512 KiB apart from the columns' tail pages
+CELL_P = 3712
+PAGE = 512 * 1024
+
+
+def _cell_pool(rng, *, distinct):
+    """A queue over the cell's pool whose keys take ``distinct`` values
+    (ties everywhere), and its service order: key descending, ties by
+    the lower page index."""
+    sizes = np.full(CELL_P, PAGE, np.int64)
+    tails = rng.choice(CELL_P, 90, replace=False)
+    sizes[tails] = rng.integers(1, PAGE // 4096, 90) * 4096
+    values = rng.choice(2**29, distinct, replace=False)
+    key = np.where(rng.random(CELL_P) < 0.2,
+                   rng.choice(values, CELL_P), -1)
+    wanted = np.flatnonzero(key >= 0)
+    order = wanted[np.lexsort((wanted, -key[wanted]))]
+    return (jnp.asarray(key, jnp.int32), jnp.asarray(sizes, jnp.float32),
+            np.cumsum(sizes[order]))
+
+
+@pytest.mark.parametrize("binds", ["first_head", "after_6", "never"])
+@pytest.mark.parametrize("pops", [0, 6, 384])
+def test_fifo_grant_cell_pool_matches_ref_exactly(pops, binds):
+    """The grant pops heads of the queue until one does not fit: a budget
+    that binds at the first head, after six heads, or never, against pop
+    caps of none, the cell's six rounds and ``vmax``, over tie-heavy keys
+    at the cell's pool."""
+    rng = np.random.default_rng(21)
+    key, sizes, csum = _cell_pool(rng, distinct=3)
+    budget = {"first_head": csum[0] - 1, "after_6": csum[5],
+              "never": 1e12}[binds]
+    _assert_grant_agrees(key, sizes, jnp.float32(budget), jnp.int32(pops),
+                         384)
+
+
+@pytest.mark.parametrize("distinct", [3, 4])
+def test_fifo_grant_ties_and_vmax_below_pops_match_ref_exactly(distinct):
+    """Keys drawn from a few values: the grant takes the lower page index
+    among equal keys; ``vmax`` below ``pops`` caps the pops."""
+    rng = np.random.default_rng(22 + distinct)
+    key, sizes, csum = _cell_pool(rng, distinct=distinct)
+    for budget in (csum[40], 1e12):
+        _assert_grant_agrees(key, sizes, jnp.float32(budget),
+                             jnp.int32(384), 17)
+
+
+def test_fifo_grant_vmapped_lanes_stop_on_their_own():
+    """Under ``jax.vmap`` each lane is its own grid step: a lane's pops
+    stop at its own budget and pop cap, whatever the other lanes grant."""
+    rng = np.random.default_rng(26)
+    pools = [_cell_pool(rng, distinct=4) for _ in range(4)]
+    key = jnp.stack([p[0] for p in pools])
+    sizes = jnp.stack([p[1] for p in pools])
+    budget = jnp.asarray([pools[0][2][0] - 1, pools[1][2][1], 1e12, 1e12],
+                         jnp.float32)
+    pops = jnp.asarray([6, 6, 6, 384], jnp.int32)
+    got = jax.vmap(functools.partial(fifo_grant_kernel, vmax=384,
+                                     interpret=True))(key, sizes, budget,
+                                                      pops)
+    want = jax.vmap(functools.partial(ref.fifo_grant_ref, vmax=384))(
+        key, sizes, budget, pops)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got[2]), [0, 2, 6, 384])
 
 
 def _sparse_blocks(rng, P, fill):

@@ -4,10 +4,11 @@ Interpret mode (every other kernel test) never runs Mosaic, so a kernel
 that the chip's compiler refuses still passes there.  These tests compile
 ``batched_evict`` (f32 and i32 keys), ``fifo_grant`` and ``wake_solve``
 for a described ``v5e:2x2`` topology, with nothing attached, at the SF30
-TPC-H pool (P = 8,832 pages) and at a ragged pool (3 blocks + 129), and
-check that the compiled program holds the Mosaic kernel under the name
-its ``pallas_call`` pins (``kernel_<op>``), the name the benchmark's
-trace reduction matches.
+TPC-H pool (P = 8,832 pages) and at a ragged pool (3 blocks + 129), the
+grant also at the benchmark cells' pool (P = 3,712) alone and under a
+four-lane ``jax.vmap``, and check that the compiled program holds the
+Mosaic kernel under the name its ``pallas_call`` pins (``kernel_<op>``),
+the name the benchmark's trace reduction matches.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so describing it while the
@@ -30,6 +31,8 @@ from repro.kernels.pbm_timeline import (
 #: the SF30 TPC-H pool (make_tpch_db(scale=1.0), 8 streams, seed 7) and a
 #: pool that leaves a ragged last page block
 POOLS = [8832, 3 * 512 + 129]
+#: the benchmark cells' pool (micro_lineitem_sf30: 3,635 pages, padded)
+CELL_POOL = 3712
 
 
 @pytest.fixture(scope="module")
@@ -87,17 +90,30 @@ def test_batched_evict_compiles(one_chip, P, key_dtype):
     assert "%kernel_batched_evict." in text
 
 
-@pytest.mark.parametrize("P", POOLS)
-def test_fifo_grant_compiles(one_chip, P):
+@pytest.mark.parametrize(
+    "P, lanes", [(P, None) for P in POOLS] + [(CELL_POOL, None),
+                                              (CELL_POOL, 4)],
+    ids=[str(P) for P in POOLS] + [str(CELL_POOL), f"4x{CELL_POOL}"])
+def test_fifo_grant_compiles(one_chip, P, lanes):
+    """The grant also compiles at the benchmark cells' pool, alone and
+    batched over four lanes as the simulator's ``jax.vmap`` calls it."""
+    batch = () if lanes is None else (lanes,)
+    fn = fifo_grant_kernel if lanes is None else jax.vmap(fifo_grant_kernel)
     text = _compile_text(
-        fifo_grant_kernel,
-        _spec(one_chip, (P,), jnp.int32),
-        _spec(one_chip, (P,), jnp.float32),
-        _spec(one_chip, (), jnp.float32),
-        _spec(one_chip, (), jnp.int32),
+        fn,
+        _spec(one_chip, batch + (P,), jnp.int32),
+        _spec(one_chip, batch + (P,), jnp.float32),
+        _spec(one_chip, batch, jnp.float32),
+        _spec(one_chip, batch, jnp.int32),
     )
     assert "tpu_custom_call" in text
-    assert "%kernel_fifo_grant." in text
+    if lanes is None:
+        assert "%kernel_fifo_grant." in text
+    else:
+        # under vmap the instruction is named vmap_kernel_fifo_grant_
+        calls = [line for line in text.splitlines()
+                 if "custom_call_target=\"tpu_custom_call\"" in line]
+        assert len(calls) == 1 and "kernel_fifo_grant" in calls[0]
 
 
 @pytest.mark.parametrize("P", POOLS)
