@@ -1,13 +1,14 @@
 """The one generator every cell's inputs come from.
 
-It reads a configuration (the tables, their widths and the query kinds)
+It reads a configuration (the tables, their widths and the queries)
 and a traffic mix (streams, bandwidth, lanes, horizon), draws the mix's
-stream set with :mod:`bench.workgen` from the mix's own ``stream_seed``, and sizes each lane's pool as the repository's sweeps
-size it: a fraction of the bytes the streams access, and never under
-4 MiB.  The run's ``--seed`` then deals the streams of that set to the
-stream slots in an order of its own: every seed runs the same set of
-scans, sizes and bytes, in another order, so a seed changes the inputs
-and not the amount of work.
+stream set from the mix's own ``stream_seed`` with the
+:mod:`bench.workgen` generator the configuration names, and sizes each
+lane's pool as the repository's sweeps size it: a fraction of the bytes
+the streams access, and never under 4 MiB.  The run's ``--seed`` then
+deals the streams of that set to the stream slots in an order of its
+own: every seed runs the same set of scans, sizes and bytes, in another
+order, so a seed changes the inputs and not the amount of work.
 """
 
 from __future__ import annotations
@@ -34,12 +35,12 @@ def build(config: dict, traffic: dict, seed: int,
           side: str = workgen.PROGRAM) -> Workload:
     """The database, the seed's streams and the lanes, built from the
     classes of ``side`` (the program's or the reference's)."""
+    draw, reckon = workgen.GENERATORS[config["generator"]]
     lib = workgen.lib_for(side)
     db = workgen.make_db(lib, config)
-    streams = workgen.streams(lib, db, config, traffic["n_streams"],
-                              traffic["queries_per_stream"],
-                              traffic["stream_seed"])
-    accessed = workgen.accessed_bytes(db, config)
+    streams = draw(lib, db, config, traffic["n_streams"],
+                   traffic["queries_per_stream"], traffic["stream_seed"])
+    accessed = reckon(db, config, streams)
     streams = deal(streams, seed)
     lanes = [(pol, frac, max(MIN_POOL, int(frac * accessed)))
              for frac in traffic["pool_fractions"]

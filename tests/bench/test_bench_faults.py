@@ -1,13 +1,14 @@
 """The faults that must turn ``correct`` false, each planted under the
-harness in the timed path of a tiny one-chip cell on the CPU, and the
-control: the regression copy with the kernels' float inputs in bfloat16
-in the program's place."""
+harness in the timed path of a tiny one-chip cell on the CPU (a micro
+cell and the tests' multi-table cell), and the control: the regression
+copy with the kernels' float inputs in bfloat16 in the program's
+place."""
 
 import pytest
 
 from bench import cells, check
 
-from benchtiny import ONE_CHIP, SEED, run
+from benchtiny import MULTI_TABLE, ONE_CHIP, SEED, find_cell, run
 
 
 def _broken(monkeypatch, fault):
@@ -52,11 +53,19 @@ def _altered(st):
     return st._replace(io_bytes=st.io_bytes.at[0].add(524288.0))
 
 
-@pytest.mark.parametrize("fault", [_unchanged, _half_batch, _altered],
-                         ids=["state_unchanged", "half_batch", "answer_altered"])
-def test_a_broken_timed_path_is_not_correct(fault, tiny, cpu, monkeypatch):
+FAULTS = {"state_unchanged": _unchanged, "half_batch": _half_batch,
+          "answer_altered": _altered}
+
+
+@pytest.mark.parametrize("name,fault", [
+    pytest.param(name, fault, id=fid if name == "micro_sf30.buf10"
+                 else f"{name}-{fid}")
+    for name in ("micro_sf30.buf10", MULTI_TABLE["name"])
+    for fid, fault in FAULTS.items()])
+def test_a_broken_timed_path_is_not_correct(name, fault, tiny, cpu,
+                                            monkeypatch):
     _broken(monkeypatch, fault)
-    cell = cells.find_cell("micro_sf30.buf10", bench_dir=tiny)
+    cell = find_cell(name, tiny)
     res = run(cell, cpu)
     assert res["correct"] is False
     assert res["failed"] > 0

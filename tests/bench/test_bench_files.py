@@ -9,7 +9,8 @@ import shutil
 import numpy as np
 import pytest
 
-from bench import cells, metrics, workload
+from bench import cells, metrics, workgen, workload
+from benchtiny import DATA
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -125,6 +126,103 @@ def test_a_cell_added_from_files_alone_is_picked_up(bench, tmp_path):
     # the cells already there are unchanged by the addition
     assert cells.find_cell("micro_sf30.buf10", extended, str(bench_dir)).traffic \
         == cells.find_cell("micro_sf30.buf10", bench).traffic
+
+
+def test_a_multi_table_cell_added_from_files_alone_is_picked_up(bench,
+                                                               tmp_path):
+    """A later change adds a ``query_templates`` configuration of three
+    tables and its traffic mix as new files plus entries; no file already
+    there is edited, and the cells already there build as before."""
+    bench_dir = tmp_path / "bench"
+    for sub in ("configs", "traffic", "layer_metrics"):
+        shutil.copytree(os.path.join(cells.BENCH_DIR, sub), bench_dir / sub)
+    cfg = json.loads(open(os.path.join(DATA, "three_tables.json")).read())
+    cfg.update(name="three_tables_sf30", scale=1.0)
+    (bench_dir / "configs" / "three_tables_sf30.json").write_text(
+        json.dumps(cfg))
+    tr = json.loads((bench_dir / "traffic" / "buf10.json").read_text())
+    tr.update(name="buf30", bandwidth_bps=6e8, pool_fractions=[0.3],
+              queries_per_stream=9)
+    (bench_dir / "traffic" / "buf30.json").write_text(json.dumps(tr))
+    extended = json.loads(json.dumps(bench))
+    extended["configs"].append({"name": "three_tables_sf30"})
+    extended["workloads"].append({"name": "three_tables_sf30.buf30",
+                                  "config": "three_tables_sf30",
+                                  "traffic": "buf30",
+                                  "chips": 1, "why": "throwaway"})
+    cell = cells.find_cell("three_tables_sf30.buf30", extended,
+                           str(bench_dir))
+    assert cell.config["generator"] == "query_templates"
+    assert {m["name"] for m in cell.end_to_end} == {"sim_s_per_s", "setup_s"}
+    wl = workload.build(cell.config, cell.traffic, 5)
+    assert len(wl.streams) == 8
+    assert {s.table for st in wl.streams for s in st} == set(cfg["tables"])
+    # 9 template instances a stream: two whole permutations and one more
+    scans = sum(len(t["scans"]) for t in cfg["templates"])
+    assert all(2 * scans + 1 <= len(st) <= 2 * scans + 3 for st in wl.streams)
+    assert wl.accessed_bytes == workgen.touched_bytes(wl.db, cell.config,
+                                                      wl.streams)
+    assert 0 < wl.accessed_bytes < sum(
+        wl.db.tables[t].total_bytes(list(cfg["tables"][t]["columns"]))
+        for t in cfg["tables"])
+    assert [cap for _, _, cap in wl.lanes] \
+        == [int(0.3 * wl.accessed_bytes)] * 4
+    # the cells already there are unchanged by the addition
+    for name in ("micro_sf30.buf40", "micro_sf30.buf10"):
+        old = cells.find_cell(name, bench)
+        new = cells.find_cell(name, extended, str(bench_dir))
+        assert (new.config, new.traffic) == (old.config, old.traffic)
+        assert workload.build(new.config, new.traffic, 5).streams \
+            == workload.build(old.config, old.traffic, 5).streams
+
+
+def _no_scans(cfg):
+    cfg["templates"][0]["scans"] = []
+
+
+def _unknown_table(cfg):
+    cfg["templates"][0]["scans"][0]["table"] = "nation"
+
+
+def _unknown_column(cfg):
+    cfg["templates"][0]["scans"][0]["columns"].append("l_comment")
+
+
+def _fraction(value):
+    def edit(cfg):
+        cfg["templates"][0]["scans"][0]["fraction"] = value
+    return edit
+
+
+def _rate_zero(cfg):
+    cfg["templates"][0]["rate"] = 0.0
+
+
+def _no_templates(cfg):
+    cfg["templates"] = []
+
+
+@pytest.mark.parametrize("edit,message", [
+    (_no_scans, "template 'Q1' has no scans"),
+    (_unknown_table, "template 'Q1': no table 'nation'"),
+    (_unknown_column, r"template 'Q1': table 'lineitem' has no columns "
+                      r"\['l_comment'\]"),
+    (_fraction(0.0), r"template 'Q1': fraction 0.0 is outside \(0, 1\]"),
+    (_fraction(1.5), r"template 'Q1': fraction 1.5 is outside \(0, 1\]"),
+    (_rate_zero, "template 'Q1': rate 0.0 is not positive"),
+    (_no_templates, "no query templates"),
+], ids=["no_scans", "unknown_table", "unknown_column", "fraction_zero",
+        "fraction_above_one", "rate_zero", "no_templates"])
+def test_a_bad_query_template_is_refused(edit, message, tmp_path):
+    cfg = json.loads(open(os.path.join(DATA, "three_tables.json")).read())
+    (tmp_path / "configs").mkdir()
+    path = tmp_path / "configs" / "three_tables.json"
+    path.write_text(json.dumps(cfg))
+    assert cells.load_config("three_tables", str(tmp_path)) == cfg
+    edit(cfg)
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(cells.CellError, match=message):
+        cells.load_config("three_tables", str(tmp_path))
 
 
 def test_a_bad_file_is_refused(tmp_path):

@@ -1,6 +1,6 @@
-"""A tiny job of each one-chip cell through the harness on the CPU
-(Pallas kernels in interpret mode), the comparison's numbers, and the
-command's refusal of a CPU."""
+"""A tiny job of each one-chip cell, and of the tests' multi-table cell,
+through the harness on the CPU (Pallas kernels in interpret mode), the
+comparison's numbers, and the command's refusal of a CPU."""
 
 import json
 import os
@@ -11,20 +11,36 @@ import numpy as np
 import pytest
 
 from bench import cells, check
-from benchtiny import ONE_CHIP, run
+from benchtiny import MULTI_TABLE, ONE_CHIP, find_cell, run
 
 
-@pytest.mark.parametrize("name", ONE_CHIP)
+@pytest.mark.parametrize("name", ONE_CHIP + [MULTI_TABLE["name"]])
 def test_tiny_job_runs_correct(name, tiny, cpu):
-    cell = cells.find_cell(name, bench_dir=tiny)
+    cell = find_cell(name, tiny)
     res = run(cell, cpu)
     assert res["correct"] is True, res["compared"]
     assert res["failed"] == 0 and res["attempted"] >= 4
     assert set(res["metrics"]) == {"sim_s_per_s", "setup_s"}
     assert res["metrics"]["sim_s_per_s"]["value"] > 0
     assert list(res)[-1] == "compared"
+    assert all(res["compared"][k]["value"] == 0 for k in check.LIMITS)
     assert res["compared"]["runner_traces"]["value"] == 1.0
     json.dumps(res)
+
+
+def test_the_multi_table_cell_changes_table_inside_a_stream(tiny):
+    """The tests' cell works what the micro cells do not: a global page
+    axis over three tables, and streams whose scans change table."""
+    from bench import workload
+    from repro.core.array_sim import compile_workload
+
+    cell = find_cell(MULTI_TABLE["name"], tiny)
+    wl = workload.build(cell.config, cell.traffic, 5)
+    assert {s.table for st in wl.streams for s in st} == set(
+        cell.config["tables"])
+    assert all(len({s.table for s in st}) == 3 for st in wl.streams)
+    spec = compile_workload(wl.db, wl.streams)
+    assert len(set(spec.q_table.ravel().tolist())) == 3
 
 
 def test_trace_run_reports_the_counted_layers(tiny, cpu):

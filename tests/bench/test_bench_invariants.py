@@ -1,12 +1,15 @@
 """The configuration's guarantees on hand-made lane states: a sound lane
 keeps each, and each breach is read."""
 
+import json
+import os
 from types import SimpleNamespace as NS
 
 import numpy as np
 import pytest
 
 from bench import cells, invariants, workgen
+from benchtiny import DATA
 
 PAGE = 524288
 CONFIG = {"page_bytes": PAGE,
@@ -43,6 +46,21 @@ def test_page_ranges_match_the_deployments_pages():
         pages = t.columns[name].pages
         assert list(b[:-1]) == [p.first_tuple for p in pages]
         assert b[-1] == pages[-1].last_tuple
+
+
+def test_page_ranges_match_every_table_of_a_multi_table_deployment():
+    """The same on each table of the tests' three-table configuration,
+    where a column of ``orders`` and of ``customer`` is one page."""
+    with open(os.path.join(DATA, "three_tables.json")) as f:
+        cfg = json.load(f)
+    db = workgen.make_db(workgen.lib_for(workgen.PROGRAM), cfg)
+    for tname, table in cfg["tables"].items():
+        t = db.tables[tname]
+        for name, width in table["columns"].items():
+            b = invariants.page_ranges(t.n_tuples, width, PAGE)
+            pages = t.columns[name].pages
+            assert list(b[:-1]) == [p.first_tuple for p in pages]
+            assert b[-1] == pages[-1].last_tuple
 
 
 def test_a_sound_lane_keeps_every_guarantee():
