@@ -6,9 +6,10 @@ Pins the behaviours the TPC-H throughput figures depend on:
   cold I/O over the union of both tables' accessed pages);
 * a stream whose consecutive queries switch tables agrees with the event
   engine under a constrained pool;
-* a vmapped (policy x buffer) sweep over the compiled TPC-H spec agrees
+* a batched (policy x buffer) sweep over the compiled TPC-H spec agrees
   with the event engine within the validated TPC-H error bars, lane for
-  lane, in ONE batched call.
+  lane, in ONE batched call: on the program's TPC-H and on the
+  benchmark's ``tpch_sf30`` configuration.
 """
 
 import jax
@@ -109,38 +110,82 @@ def test_stream_switching_tables_matches_event_engine():
 
 # ----------------------------- vmapped TPC-H sweep vs event engine --------
 
-def test_vmapped_tpch_four_policy_buffer_sweep_within_validation_bars():
+def _program_tpch(scale, n_streams, seed):
+    """The program's TPC-H (``_TPCH_TABLES``, ``_TPCH_QUERIES``)."""
+    db = make_tpch_db(scale=scale)
+    streams = tpch_streams(db, n_streams=n_streams, seed=seed)
+    return db, streams, tpch_accessed_bytes(db, streams)
+
+
+def _tpch_sf30(scale, n_streams, seed):
+    """The tables and templates of the benchmark's ``tpch_sf30``
+    configuration: the specification's columns, each stream one
+    permutation of the 22 templates."""
+    from bench import cells, workgen
+
+    cfg = dict(cells.load_config("tpch_sf30"), scale=scale)
+    lib = workgen.lib_for(workgen.PROGRAM)
+    db = workgen.make_db(lib, cfg)
+    streams = workgen.template_streams(lib, db, cfg, n_streams,
+                                       len(cfg["templates"]), seed)
+    return db, streams, workgen.touched_bytes(db, cfg, streams)
+
+
+def _vmapped(runner, cfgs):
+    return jax.jit(jax.vmap(runner))(cfgs)
+
+
+def _mapped(runner, cfgs):
+    """One call that runs each lane to its own end: a vmap would hold
+    every lane to the slowest one's steps."""
+    return jax.jit(lambda c: jax.lax.map(runner, c))(cfgs)
+
+
+#: (workload, time engine, batching, buffer points where CScan must beat
+#: OPT).  On ``tpch_sf30`` the event engine itself puts CScan behind OPT
+#: from pool 0.3 up (3.987 s against 3.924 s at 0.3, 3.041 against 2.995
+#: at 0.5), so CScan's lead is held to at 0.15 only.
+TPCH_CASES = {
+    "program": (_program_tpch, "fixed", _vmapped, (0.15, 0.3, 0.5)),
+    "tpch_sf30": (_tpch_sf30, "horizon", _mapped, (0.15,)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TPCH_CASES))
+def test_vmapped_tpch_four_policy_buffer_sweep_within_validation_bars(case):
     """The acceptance shape of the ArrayPolicy tentpole: the FULL paper
     comparison — all four policies (lru / cscan / pbm / opt) x every
     validated buffer point — over the compiled TPC-H spec runs as ONE
-    vmapped computation, and every lane agrees with the event engine
+    batched computation, and every lane agrees with the event engine
     within the validated TPC-H bars (<= 15% for the array-CScan /
     array-OPT ports).  Uses the quick-pass TPC-H point the bars were
-    fit at."""
+    fit at, on the program's TPC-H (the fixed stepper) and on the
+    benchmark's ``tpch_sf30`` tables and templates (the horizon stepper,
+    which the benchmark's cell times)."""
     from repro.core.policy_registry import names as policy_names
 
+    build, stepper, batch, cscan_ahead = TPCH_CASES[case]
     policies = policy_names(backend="array")
     assert set(policies) == {"lru", "cscan", "pbm", "opt"}
     scale = TPCH_DEFAULTS["scale"]
     bw = TPCH_DEFAULTS["bandwidth"]
-    db = make_tpch_db(scale=scale)
-    streams = tpch_streams(db, n_streams=TPCH_DEFAULTS["n_streams"],
-                           seed=TPCH_DEFAULTS["seed"])
-    ws = tpch_accessed_bytes(db, streams)
+    db, streams, ws = build(scale, TPCH_DEFAULTS["n_streams"],
+                            TPCH_DEFAULTS["seed"])
     spec = compile_workload(db, streams)
     assert spec.n_tables >= 6          # the TPC-H fact + dimension tables
     assert spec.n_cols >= 50
     time_slice = 0.1 * scale
     # one runner over the whole registry: the policy axis itself is a
     # traced config scalar (the default policies=None means "all")
-    runner = make_runner(spec, bandwidth_ref=bw, time_slice=time_slice)
+    runner = make_runner(spec, bandwidth_ref=bw, time_slice=time_slice,
+                         stepper=stepper)
     fracs = sorted({f for (f, _p) in TPCH_ERROR_BARS})
     lanes = [(f, pol) for f in fracs for pol in policies]
     cfgs = stack_configs([
         make_config(spec, max(1 << 22, int(f * ws)), bw, pol)
         for f, pol in lanes
     ])
-    states = jax.block_until_ready(jax.jit(jax.vmap(runner))(cfgs))
+    states = jax.block_until_ready(batch(runner, cfgs))
     ios, times = {}, {}
     for i, (f, pol) in enumerate(lanes):
         ar = result_from_state(jax.tree.map(lambda x, i=i: x[i], states), pol)
@@ -162,10 +207,12 @@ def test_vmapped_tpch_four_policy_buffer_sweep_within_validation_bars():
         for a, b in zip(seq, seq[1:]):
             assert b <= a * 1.05, (pol, seq)
     # the paper's policy ordering holds on the array backend too: the
-    # cooperative scans beat every order-preserving policy, and OPT
-    # bounds LRU, at every validated buffer point (Figs 14-16)
+    # cooperative scans beat every order-preserving policy where the
+    # case holds them to it, and OPT bounds LRU at every validated
+    # buffer point (Figs 14-16)
     for f in fracs:
-        assert times[(f, "cscan")] < times[(f, "opt")], (f, times)
+        if f in cscan_ahead:
+            assert times[(f, "cscan")] < times[(f, "opt")], (f, times)
         assert times[(f, "opt")] < times[(f, "lru")], (f, times)
 
 
