@@ -357,6 +357,9 @@ def _c_fifo_grant_tail():
 
 
 def _c_wake_solve():
+    """The grant's geometry: one grid step (``jax.vmap`` adds the lane
+    axis) holding the whole pool row in VMEM, dense as (P_pad / 128,
+    128), with ``pops``, ``credit0`` and ``inc`` in SMEM."""
     import jax.numpy as jnp
     from repro.kernels.pbm_timeline import wake_solve_kernel
 
@@ -368,10 +371,9 @@ def _c_wake_solve():
 
 
 def _c_wake_solve_tail():
-    """Wake-solve at the ragged-tail geometry (P = 3 x _BLOCK + 129):
-    its global scratch rows are sized to the PADDED pool, so the
-    footprint and write-coverage checks must pass with the tail block
-    present."""
+    """The same geometry at a ragged pool: P = 3 x 512 + 129 pads to
+    2,048 pages, (16, 128), so the footprint and write-coverage checks
+    must pass with padding in play."""
     import jax.numpy as jnp
     from repro.kernels.pbm_timeline import wake_solve_kernel
 

@@ -1277,11 +1277,20 @@ def make_step(spec: SimSpec, dt: float, time_slice: float,
                 # frozen entry, so the predicted prefix is exact)
                 stamp_age3 = jnp.clip(state.steps + 2 - req_step3, 0, 32767)
                 wake_key = jnp.where(wanted3, stamp_age3 * 32768 + tie15, -1)
+                # only a saturated in-order lane reads the solve (t_io_pend
+                # below; cooperative lanes keep their own candidates): every
+                # other lane asks it for zero pops, and it pops nothing
+                wake_pops = _sel(is_coop, jnp.int32(0),
+                                 jnp.where(sat, _LOAD_MAX, 0))
                 wake_step = kops.wake_solve(
                     wake_key, page_size, io_credit2,
-                    cfg.bandwidth * dt_ref, jnp.int32(_LOAD_MAX),
+                    cfg.bandwidth * dt_ref, wake_pops,
                     h_cap=wake_cap_i,
                 )
+                if tele2 is not None:
+                    with phases.phase("obs"):
+                        tele2 = tele2._replace(wake_solves=obs.count(
+                            tele2.wake_solves, wake_pops > 0))
                 # a blocked stream wakes when EVERY absent page it sits on
                 # (trigger at/behind the cursor, all columns) is granted:
                 # per-stream max over those pages' grant steps, then the

@@ -248,8 +248,9 @@ def wake_solve(key, sizes, credit0, inc, pops, *, h_cap: int = 64):
     Pages not wanted (``key < 0``) or not granted within ``h_cap`` fine
     steps carry the sentinel ``h_cap + 1``.  Called from inside the
     already-jitted event-horizon step, so no jit wrapper; backend policy
-    picks the page-blocked Mosaic kernel on TPU and the jnp oracle (one
-    stable argsort + the pop-rate recursion) elsewhere.  Under page
+    picks the head-of-queue Mosaic kernel on TPU and the jnp oracle (one
+    stable argsort + the pop-rate recursion) elsewhere.  ``pops = 0``
+    pops nothing: every page gets the sentinel.  Under page
     sharding the inputs are replicated and the solve's outputs feed
     lane-global jump decisions, so it runs replicated as-is.
 

@@ -15,14 +15,14 @@ selecting between their score arrays.
 Design notes
 ------------
 * All per-page state is dense ``(1, P)`` rows; wrappers pad P up to a
-  multiple of ``_BLOCK`` (the grant's to a multiple of ``_DENSE``) with
-  exact sentinels (non-wanted key, zero size, non-evictable) and slice
-  the padding back off, so any P works and every BlockSpec divides its
-  operand.
-* Victim selection and the wake solve are prefix sums over the priority
-  order.  Instead of sorting (awkward on the VPU), we compute for every
-  page the bytes that would be freed/served *before* it via a masked
-  comparison tile contracted against page sizes on the MXU.
+  multiple of ``_BLOCK`` (the grant's and the wake solve's to a
+  multiple of ``_DENSE``) with exact sentinels (non-wanted key, zero
+  size, non-evictable) and slice the padding back off, so any P works
+  and every BlockSpec divides its operand.
+* Victim selection is a prefix sum over the priority order.  Instead of
+  sorting (awkward on the VPU), we compute for every page the bytes
+  that would be freed *before* it via a masked comparison tile
+  contracted against page sizes on the MXU.
 * That O(P^2) prefix work is **gridded over page blocks**: grid
   ``(i, j)`` walks (row-block, col-block) tiles of the comparison
   matrix with j innermost, accumulating per-row prefix bytes and ranks
@@ -30,22 +30,21 @@ Design notes
   ``pl.when(j == n_j - 1)`` — the sanctioned accumulator-revisit
   pattern).  Per-step VMEM is O(_BLOCK^2) regardless of P, so
   P >> VMEM satisfies the contract verifier's vmem-budget rule.
-  The wake kernel's passes that need a *global* intermediate (per-page
-  rank/prefix bytes) run as an extra leading phase axis: TPU grids are
-  sequential, so phase 0 fully populates the (1, P_pad) scratch before
-  phase 1 reads it.
-* A tile whose row or column page block holds no candidate (queued or
-  evictable page) adds exactly zero, so the kernels skip it under
-  ``pl.when`` on a per-block occupancy row passed in SMEM.
-* The grant needs no prefix over the whole queue: it is a prefix of the
-  service order that stops at the first head that does not fit, and a
-  call grants a handful of pages.  So it runs one grid step per lane
-  (``jax.vmap`` adds the lane axis) over the lane's key and size rows
-  held whole in VMEM, dense as ``(P_pad / 128, 128)``, and pops heads —
-  a max over the keys, the lowest page index among the ties, a masked
-  sum for the size — until one is not wanted, exceeds the pop cap or
-  overruns the byte budget.  Its work is one vector pass per page
-  granted plus one; its VMEM is O(P): 24 bytes a page, double-buffered.
+* A tile whose row or column page block holds no evictable page adds
+  exactly zero, so the kernel skips it under ``pl.when`` on a per-block
+  occupancy row passed in SMEM.
+* The grant and the wake solve need no prefix over the whole queue:
+  each reads a prefix of the service order that ends at the first head
+  past a limit, and a call takes a handful of pages.  So each runs one
+  grid step per lane (``jax.vmap`` adds the lane axis) over the lane's
+  key and size rows held whole in VMEM, dense as ``(P_pad / 128,
+  128)``, and pops heads — a max over the keys, the lowest page index
+  among the ties, a masked sum for the size.  The grant stops at a head
+  that is not wanted, exceeds the pop cap or overruns the byte budget;
+  the wake solve at an empty queue, at rank ``h_cap * pops`` or at a
+  head whose bytes no step's credit covers, so a call with ``pops = 0``
+  pops nothing.  The work is one vector pass per page popped plus one;
+  VMEM is O(P): 24 bytes a page, double-buffered.
 
 Semantics are defined by the oracles in ``repro.kernels.ref``
 (``batched_evict_ref`` / ``fifo_grant_ref`` / ``wake_solve_ref``);
@@ -162,6 +161,21 @@ def _kernel(fscal_ref, occ_ref, key_i_ref, key_j_ref, sizes_j_ref, ev_i_ref,
         evict_out_ref[...] = take.astype(jnp.float32)
 
 
+def _dense_index(shape) -> jax.Array:
+    """Page index of each slot of a dense ``(rows, 128)`` pool row."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * _LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _head(key, idx, sizes):
+    """The head of the queue in service order: the highest key, ties by
+    the lower page index.  Returns its key, its one-hot mask and its
+    size."""
+    top = jnp.max(key)
+    head = idx == jnp.min(jnp.where(key == top, idx, key.size))
+    return top, head, jnp.sum(jnp.where(head, sizes, 0.0))
+
+
 def _grant_kernel(iscal_ref, fscal_ref, key_ref, sizes_ref, grant_out_ref,
                   *, vmax: int):
     cap = jnp.minimum(iscal_ref[0, 0], vmax)
@@ -170,17 +184,13 @@ def _grant_kernel(iscal_ref, fscal_ref, key_ref, sizes_ref, grant_out_ref,
     sizes = sizes_ref[...]            # use up to ~30 bits (stamp*32768 +
                                       # tie), so an f32 cast would round
                                       # away the tie bits beyond 2^24
-    idx = (jax.lax.broadcasted_iota(jnp.int32, key0.shape, 0) * _LANES
-           + jax.lax.broadcasted_iota(jnp.int32, key0.shape, 1))
-    p_pad = key0.size
+    idx = _dense_index(key0.shape)
 
     def pop(carry):
-        # one head of the queue: the highest wanted key, ties by the lower
-        # page index; it is granted only if it fits, else it blocks the rest
+        # the head is granted only if it is wanted and fits, else it
+        # blocks the rest
         _, n, nbytes, key = carry
-        top = jnp.max(key)
-        head = idx == jnp.min(jnp.where(key == top, idx, p_pad))
-        size = jnp.sum(jnp.where(head, sizes, 0.0))
+        top, head, size = _head(key, idx, sizes)
         fits = (top >= 0) & (n < cap) & (nbytes + size <= budget)
         return (fits, n + fits.astype(jnp.int32),
                 jnp.where(fits, nbytes + size, nbytes),
@@ -192,84 +202,91 @@ def _grant_kernel(iscal_ref, fscal_ref, key_ref, sizes_ref, grant_out_ref,
     grant_out_ref[...] = (key != key0).astype(jnp.int32)
 
 
-def _wake_kernel(iscal_ref, fscal_ref, occ_ref, key_i_ref, key_j_ref,
-                 sizes_i_ref, sizes_j_ref, wake_out_ref,
-                 csum_ref, rank_ref, bytes_acc_ref, rank_acc_ref,
-                 cnt_ref, nk_ref,
-                 *, h_cap: int, block: int, n_j: int):
-    ph = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    pops = iscal_ref[0, 0]
+def _wake_kernel(iscal_ref, fscal_ref, key_ref, sizes_ref, wake_out_ref,
+                 *, h_cap: int):
+    pops = jnp.maximum(iscal_ref[0, 0], 0)
     credit0 = fscal_ref[0, 0]
     inc = fscal_ref[0, 1]
+    key0 = key_ref[...]               # (rows, 128) i32 FIFO keys (~30 bits)
+    sizes = sizes_ref[...]
+    idx = _dense_index(key0.shape)
+    p_pad = key0.size
+    # the credit banked after k fine steps, k = 1..h_cap; no page whose
+    # prefix-inclusive bytes exceed all of them is granted within h_cap
+    bounds = credit0 + (_iota_f32((1, h_cap), 1) + 1.0) * inc
+    reach = jnp.max(bounds)
+    # n_k <= k * pops: no page at or past rank h_cap * pops is granted
+    limit = jnp.minimum(pops, p_pad) * h_cap
 
-    wanted_p = key_i_ref[:] >= 0
-    wanted_q = key_j_ref[:] >= 0
+    def pop(carry):
+        # the head takes the next rank while it is wanted and its
+        # prefix-inclusive bytes stay within reach
+        _, n, nbytes, key, rank, cnt = carry
+        top, head, size = _head(key, idx, sizes)
+        csum = nbytes + size
+        take = (top >= 0) & (csum <= reach)
+        return (take & (n + 1 < limit), n + take.astype(jnp.int32), csum,
+                jnp.where(head & take, -1, key),
+                jnp.where(head & take, n, rank),
+                cnt + (take & (csum <= bounds)).astype(jnp.float32))
 
-    # ---- phase 0: service rank + prefix-inclusive queue bytes per page ---
-    @pl.when((ph == 0) & (j == 0))
-    def _init_prefix():
-        bytes_acc_ref[...] = jnp.zeros_like(bytes_acc_ref)
-        rank_acc_ref[...] = jnp.zeros_like(rank_acc_ref)
+    # rank p_pad (past every n_k) marks a page not popped: its step is the
+    # sentinel h_cap + 1, as is every page on a zero-pop call
+    start = (limit > 0, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
+             key0, jnp.full(key0.shape, p_pad, jnp.int32),
+             jnp.zeros((1, h_cap), jnp.float32))
+    _, n, _, _, rank, cnt = jax.lax.while_loop(
+        lambda carry: carry[0], pop, start)
+    wake_out_ref[...] = jnp.full(key0.shape, h_cap + 1, jnp.int32)
 
-    # tiles whose row or column block holds no queued page add nothing
-    @pl.when((ph == 0) & (occ_ref[0, i] > 0) & (occ_ref[0, j] > 0))
-    def _acc_prefix():
-        before = _order_before(key_i_ref[:].reshape(block, 1), key_j_ref[:],
-                               i, j, block) & wanted_q
-        sz = (sizes_j_ref[:] * wanted_q).reshape(block, 1)
-        bytes_acc_ref[...] = bytes_acc_ref[...] + _prefix_dot(
-            before, sz).reshape(1, block)
-        rank_acc_ref[...] = rank_acc_ref[...] + jnp.sum(
-            before, axis=1, dtype=jnp.float32,
-        ).reshape(1, block)
-
-    @pl.when((ph == 0) & (j == n_j - 1))
-    def _store_prefix():
-        own = sizes_i_ref[:] * wanted_p
-        csum_ref[0, pl.ds(i * block, block)] = \
-            (bytes_acc_ref[...] + own).reshape(block)
-        rank_ref[0, pl.ds(i * block, block)] = rank_acc_ref[...].reshape(block)
-
-    # ---- phase 1: grants the banked credit alone allows after k steps ----
-    @pl.when((ph == 1) & (i == 0) & (j == 0))
-    def _init_cnt():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    @pl.when((ph == 1) & (i == 0) & (occ_ref[0, j] > 0))
-    def _acc_cnt():
-        cs = csum_ref[0, pl.ds(j * block, block)].reshape(1, block)
-        ks = _iota_f32((h_cap, block), 0) + 1.0
-        ok = wanted_q & (cs <= credit0 + ks * inc)
-        cnt_ref[...] = cnt_ref[...] + jnp.sum(
-            ok, axis=1, dtype=jnp.float32,
-        ).reshape(h_cap, 1)
-
-    # ---- phase 2: pop-rate recursion, then per-page wake step ------------
-    # n_k = min(cnt_k, n_{k-1} + pops) unrolled to
-    # min(min_{1<=jj<=k}(cnt_jj + (k-jj)*pops), k*pops) — one (h_cap, h_cap)
-    # min-plus tile instead of a sequential scan
-    @pl.when((ph == 2) & (i == 0) & (j == 0))
-    def _solve_ramp():
-        popf = jnp.maximum(pops, 0).astype(jnp.float32)
-        kk = _iota_f32((h_cap, h_cap), 0) + 1.0
-        jj = _iota_f32((h_cap, h_cap), 1) + 1.0
-        gap = kk - jj
-        ramp = jnp.where(
-            gap >= 0, cnt_ref[...].reshape(1, h_cap) + gap * popf, jnp.inf)
+    @pl.when(n > 0)
+    def _solve():
+        # pop-rate recursion n_k = min(cnt_k, n_{k-1} + pops) unrolled to
+        # min(min_{1<=j<=k}(cnt_j + (k-j)*pops), k*pops) — one
+        # (h_cap, h_cap) min-plus tile instead of a sequential scan
+        popf = pops.astype(jnp.float32)
+        gap = _iota_f32((h_cap, h_cap), 0) - _iota_f32((h_cap, h_cap), 1)
+        ramp = jnp.where(gap >= 0, cnt + gap * popf, jnp.inf)
         ks = _iota_f32((h_cap, 1), 0) + 1.0
-        nk_ref[...] = jnp.minimum(
-            jnp.min(ramp, axis=1).reshape(h_cap, 1), ks * popf)
+        nk = jnp.minimum(jnp.min(ramp, axis=1, keepdims=True), ks * popf)
+        kcol = jax.lax.broadcasted_iota(jnp.int32, (h_cap, 1), 0)
+        rank1 = rank.astype(jnp.float32) + 1.0
 
-    @pl.when((ph == 2) & (j == n_j - 1))
-    def _commit():
-        rk = rank_ref[0, pl.ds(i * block, block)].reshape(1, block)
-        step = 1.0 + jnp.sum(
-            nk_ref[...] < (rk + 1.0), axis=0, dtype=jnp.float32,
-        ).reshape(1, block)
-        wake_out_ref[...] = jnp.where(
-            wanted_p, step, float(h_cap + 1)).astype(jnp.int32)
+        def later(k, acc):
+            # each step k whose n_k stays short of rank + 1 delays the page
+            nk_k = jnp.sum(jnp.where(kcol == k, nk, 0.0))
+            return acc + (nk_k < rank1).astype(jnp.int32)
+
+        wake_out_ref[...] = 1 + jax.lax.fori_loop(
+            0, h_cap, later, jnp.zeros(key0.shape, jnp.int32))
+
+
+def _queue_call(kernel, name: str, iscal, fscal, key, sizes,
+                interpret: bool) -> jax.Array:
+    """One grid step of a head-of-queue kernel over a lane's (P,) key and
+    size rows, padded to a multiple of ``_DENSE`` with exact sentinels
+    (key -1, size 0) and held whole in VMEM, dense as ``(P_pad / 128,
+    128)``; ``iscal`` (i32) and ``fscal`` (f32) ride SMEM.  Returns the
+    kernel's (P,) i32 row."""
+    P = key.shape[0]
+    p_pad = -(-P // _DENSE) * _DENSE
+    rows = p_pad // _LANES
+    key_2d = _pad_row(key.reshape(1, P).astype(jnp.int32), p_pad,
+                      -1).reshape(rows, _LANES)
+    sz_2d = _pad_row(sizes.reshape(1, P).astype(jnp.float32), p_pad,
+                     0.0).reshape(rows, _LANES)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    whole = pl.BlockSpec((rows, _LANES), lambda: (0, 0),
+                         memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
+        in_specs=[smem, smem, whole, whole],
+        out_specs=whole,
+        interpret=interpret,
+        name=name,
+    )(iscal, fscal, key_2d, sz_2d)
+    return out.reshape(p_pad)[:P]
 
 
 def fifo_grant_kernel(
@@ -289,27 +306,12 @@ def fifo_grant_kernel(
     granted_bytes, n_granted)``; semantics defined by
     ``ref.fifo_grant_ref`` (tests assert exact agreement in interpret
     mode)."""
-    P = key.shape[0]
-    p_pad = -(-P // _DENSE) * _DENSE
-    rows = p_pad // _LANES
-    key_2d = _pad_row(key.reshape(1, P).astype(jnp.int32), p_pad,
-                      -1).reshape(rows, _LANES)
-    sz_2d = _pad_row(sizes.reshape(1, P).astype(jnp.float32), p_pad,
-                     0.0).reshape(rows, _LANES)
-    iscal = jnp.asarray(pops, jnp.int32).reshape(1, 1)
-    fscal = jnp.asarray(budget, jnp.float32).reshape(1, 1)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    whole = pl.BlockSpec((rows, _LANES), lambda: (0, 0),
-                         memory_space=pltpu.VMEM)
-    grant = pl.pallas_call(
-        functools.partial(_grant_kernel, vmax=min(vmax, P)),
-        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
-        in_specs=[smem, smem, whole, whole],
-        out_specs=whole,
-        interpret=interpret,
-        name="kernel_fifo_grant",
-    )(iscal, fscal, key_2d, sz_2d)
-    mask = grant.reshape(p_pad)[:P] > 0
+    grant = _queue_call(
+        functools.partial(_grant_kernel, vmax=min(vmax, key.shape[0])),
+        "kernel_fifo_grant", jnp.asarray(pops, jnp.int32).reshape(1, 1),
+        jnp.asarray(budget, jnp.float32).reshape(1, 1), key, sizes,
+        interpret)
+    mask = grant > 0
     granted = jnp.where(mask, sizes, 0.0)
     return mask, jnp.sum(granted), jnp.sum(mask)
 
@@ -378,46 +380,24 @@ def wake_solve_kernel(
     interpret: bool = False,
 ) -> jax.Array:
     """Per-page grant step of the frozen serial I/O server (the
-    event-horizon stepper's wake-exact queue model), page-blocked.
-
-    Grid = (phase, i, j): phase 0 writes every page's service rank and
-    prefix-inclusive queue bytes into global scratch, phase 1 folds them
-    into per-step feasible grant counts ``cnt_k``, phase 2 solves the
-    pop-rate recursion ``n_k = min(cnt_k, n_{k-1} + pops)`` as one
-    min-plus tile and emits each page's first ``k`` with
-    ``n_k >= rank + 1``.  Returns ``(P,) i32`` in ``1..h_cap`` with
-    sentinel ``h_cap + 1``; semantics defined by ``ref.wake_solve_ref``
-    (tests assert exact agreement in interpret mode)."""
-    P = key.shape[0]
-    p_pad, n_b = _blocks(P)
-    key_row = _pad_row(key.reshape(1, P).astype(jnp.int32), p_pad, -1)
-    sz_row = _pad_row(sizes.reshape(1, P).astype(jnp.float32), p_pad, 0.0)
-    iscal = jnp.asarray(pops, jnp.int32).reshape(1, 1)
+    event-horizon stepper's wake-exact queue model) by head-of-queue
+    extraction: one grid step holds the lane's key and size rows whole
+    in VMEM, dense as ``(P_pad / 128, 128)``, and pops heads in service
+    order, recording each one's rank and its prefix-inclusive bytes'
+    share of the per-step feasible counts ``cnt_k``.  It stops at an
+    empty queue, at rank ``h_cap * pops`` or at the first head whose
+    bytes exceed ``credit0 + h_cap * inc``: no page behind it is granted
+    within ``h_cap`` steps.  Then it solves the pop-rate recursion
+    ``n_k = min(cnt_k, n_{k-1} + pops)`` as one min-plus tile and gives
+    each popped page the first ``k`` with ``n_k >= rank + 1``.  A call
+    with ``pops = 0`` pops nothing.  Returns ``(P,) i32`` in
+    ``1..h_cap`` with sentinel ``h_cap + 1``; semantics defined by
+    ``ref.wake_solve_ref`` (tests assert exact agreement in interpret
+    mode)."""
     fscal = jnp.stack([
         jnp.asarray(credit0, jnp.float32), jnp.asarray(inc, jnp.float32),
     ]).reshape(1, 2)
-    occ = _occupied(key_row >= 0, n_b)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    row_i = pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
-                         memory_space=pltpu.VMEM)
-    row_j = pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, j),
-                         memory_space=pltpu.VMEM)
-    wake = pl.pallas_call(
-        functools.partial(_wake_kernel, h_cap=h_cap, block=_BLOCK, n_j=n_b),
-        grid=(3, n_b, n_b),
-        out_shape=jax.ShapeDtypeStruct((1, p_pad), jnp.int32),
-        in_specs=[smem, smem, smem, row_i, row_j, row_i, row_j],
-        out_specs=pl.BlockSpec((1, _BLOCK), lambda p, i, j: (0, i),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((1, p_pad), jnp.float32),    # prefix bytes (global)
-            pltpu.VMEM((1, p_pad), jnp.float32),    # service rank (global)
-            pltpu.VMEM((1, _BLOCK), jnp.float32),   # prefix-bytes accumulator
-            pltpu.VMEM((1, _BLOCK), jnp.float32),   # rank accumulator
-            pltpu.VMEM((h_cap, 1), jnp.float32),    # cnt_k
-            pltpu.VMEM((h_cap, 1), jnp.float32),    # n_k
-        ],
-        interpret=interpret,
-        name="kernel_wake_solve",
-    )(iscal, fscal, occ, key_row, key_row, sz_row, sz_row)
-    return wake[0, :P]
+    return _queue_call(
+        functools.partial(_wake_kernel, h_cap=h_cap), "kernel_wake_solve",
+        jnp.asarray(pops, jnp.int32).reshape(1, 1), fscal, key, sizes,
+        interpret)

@@ -36,6 +36,10 @@ Counter taxonomy (one :class:`Telemetry` per lane; vmap batches them):
                  integrated over steps / peak
 ``chunk_picks``  cooperative chunk selections (the I/O server switching
                  to a new CScan chunk)
+``wake_solves``  horizon steps whose wake solve was asked for more than
+                 zero pops (a supersaturated in-order lane under
+                 ``wake_exact``; every other lane discards the solve and
+                 asks it for none)
 ``pol_obs``      per compiled policy, the row its ``observe`` hook
                  accumulates (PBM: bucket occupancy histogram; LRU:
                  resident age mass; OPT: referenced/unreferenced split;
@@ -68,6 +72,7 @@ class Telemetry(NamedTuple):
     ioq_depth_sum: jax.Array  # i32 pending-request depth, step-integrated
     ioq_depth_max: jax.Array  # i32 pending-request depth, peak
     chunk_picks: jax.Array    # i32 cooperative chunk selections
+    wake_solves: jax.Array    # i32 steps whose wake solve was asked to pop
     pol_obs: Tuple = ()       # per-policy observe rows (f32 vectors)
 
 
@@ -106,7 +111,7 @@ def init_telemetry(policies, spec) -> Telemetry:  # analysis: host
     return Telemetry(
         hits=z, misses=z, loads=z, evictions=z,
         evict_rank=zh, jump_hist=zh,
-        ioq_depth_sum=z, ioq_depth_max=z, chunk_picks=z,
+        ioq_depth_sum=z, ioq_depth_max=z, chunk_picks=z, wake_solves=z,
         pol_obs=tuple(rows),
     )
 
@@ -132,6 +137,7 @@ def summarize(tele: Telemetry, policies=None, steps=None) -> dict:
         "jump_hist": np.asarray(tele.jump_hist).tolist(),
         "ioq_depth_max": int(tele.ioq_depth_max),
         "chunk_picks": int(tele.chunk_picks),
+        "wake_solves": int(tele.wake_solves),
     }
     if steps is not None and int(steps) > 0:
         out["ioq_depth_mean"] = round(

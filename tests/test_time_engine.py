@@ -23,6 +23,7 @@ tests pin the contracts the refactor introduced:
 * the budgeted FIFO-grant kernel matches its jnp oracle exactly.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -476,6 +477,48 @@ def test_wake_exact_no_effect_outside_saturation():
             np.asarray(getattr(runs["on"], name)), err_msg=name)
 
 
+def test_wake_solve_pops_only_on_lanes_that_read_it():
+    """Only a supersaturated in-order lane reads the wake solve, so only
+    such a lane asks it to pop.  Four policy lanes at a supersaturated
+    and at a non-saturated pool, one ``vmap``: the interpret-mode Pallas
+    kernels and the jnp oracles give bit-identical final states and
+    counters, and ``wake_solves`` counts steps on the saturated lru, pbm
+    and opt lanes only — never on cscan, never at the roomy pool."""
+    from repro.kernels import ops
+
+    db, ws, streams = _micro_shared()
+    spec = build_spec(db, streams)
+    sat_bytes = spec.n_streams * 8 * float(np.max(spec.page_size))
+    pools = (int(0.1 * ws), int(0.2 * ws))
+    assert pools[0] < sat_bytes <= pools[1]
+    pols = ("lru", "cscan", "pbm", "opt")
+    cfgs = stack_configs([make_config(spec, cap, 700e6, p, max_time=0.2)
+                          for cap in pools for p in pols])
+    runs = {}
+    try:
+        for backend in ("reference", "interpret"):
+            ops.set_backend(backend)
+            runner = make_runner(spec, bandwidth_ref=700e6, time_slice=0.01,
+                                 policies=pols, stepper="horizon",
+                                 telemetry=True)
+            runs[backend] = jax.block_until_ready(
+                jax.jit(jax.vmap(runner))(cfgs))
+    finally:
+        ops.set_backend("auto")
+    for a, b in zip(jax.tree.leaves(runs["reference"]),
+                    jax.tree.leaves(runs["interpret"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    state, tele = runs["interpret"]
+    solves = np.asarray(tele.wake_solves)
+    in_order = np.array([p != "cscan" for p in pols])
+    assert (solves[:4][in_order] > 0).all()
+    assert solves[:4][~in_order].sum() == 0
+    assert solves[4:].sum() == 0
+    # the horizon stepper asks once per macro-step of a reading lane
+    np.testing.assert_array_equal(solves[:4][in_order],
+                                  np.asarray(state.steps)[:4][in_order])
+
+
 # ------------------------------------------------ fifo-grant kernel -------
 
 def test_fifo_grant_kernel_matches_reference_interpret():
@@ -510,15 +553,76 @@ def test_fifo_grant_kernel_matches_reference_interpret():
         assert int(nr_) == int(nk)
 
 
-def test_wake_solve_kernel_matches_reference_interpret():
+def _wake_case(name, rng):
+    """Inputs ``(key, sizes, credit0, inc, pops)`` of one named wake-solve
+    case; leading axis of four lanes for ``vmap_mixed_pops``."""
+    def fifo_keys(P, wanted=1.0):
+        key = rng.integers(0, (32767 << 15) + 32767, P)
+        return np.where(rng.random(P) < wanted, key, -1)
+
+    def page_sizes(P):
+        return rng.choice([524288.0, 262144.0, 4096.0], P)
+
+    f32, i32 = np.float32, np.int32
+    if name == "zero_pops":       # a lane that discards the solve
+        P = 600
+        return fifo_keys(P), page_sizes(P), f32(1e9), f32(1e7), i32(0)
+    if name == "deeper_than_cap":  # credit for all; h_cap * pops stops it
+        P = 700
+        return fifo_keys(P), page_sizes(P), f32(1e12), f32(1e9), i32(3)
+    if name == "credit_bound":    # the bytes stop it before h_cap * pops
+        P = 700
+        return fifo_keys(P), page_sizes(P), f32(2e5), f32(3e5), i32(40)
+    if name in ("ragged_1061", "ragged_3712"):
+        P = 1024 + 37 if name == "ragged_1061" else 3712
+        return (fifo_keys(P, 0.3), page_sizes(P), f32(1e6), f32(8e5),
+                i32(6))
+    if name == "dense_ties":      # equal keys past 2^24: the page index rules
+        P = 500
+        key = (1 << 26) + rng.integers(-2, 4, P) * 3
+        return key, page_sizes(P), f32(3e5), f32(6e5), i32(6)
+    assert name == "vmap_mixed_pops"
+    P = 900
+    key = np.stack([fifo_keys(P, 0.4) for _ in range(4)])
+    sizes = np.stack([page_sizes(P) for _ in range(4)])
+    return (key, sizes, np.full(4, 1e6, f32), np.full(4, 6e5, f32),
+            np.array([0, 6, 0, 6], i32))
+
+
+@pytest.mark.parametrize("case", [
+    "seeded", "zero_pops", "deeper_than_cap", "credit_bound", "ragged_1061",
+    "ragged_3712", "dense_ties", "vmap_mixed_pops"])
+def test_wake_solve_kernel_matches_reference_interpret(case):
     """The wake-solve kernel (per-page grant step of the frozen serial
-    I/O server) must agree exactly with the jnp oracle — including the
-    ragged-tail blocked geometry (P not a multiple of the page block)
-    and the not-granted sentinel ``h_cap + 1``."""
+    I/O server) must agree exactly with the jnp oracle: on seeded queues
+    at P = 128 and 549 (the not-granted sentinel ``h_cap + 1`` on an
+    empty queue), with zero pops (every page the sentinel), a queue
+    deeper than ``h_cap * pops``, a credit bound that stops the pops
+    first, pools that leave a ragged last row, dense key ties past 2^24,
+    and four ``jax.vmap``-ed lanes with mixed pops."""
     from repro.kernels.pbm_timeline import wake_solve_kernel
     from repro.kernels.ref import wake_solve_ref
 
     rng = np.random.default_rng(23)
+    if case != "seeded":
+        args = [jnp.asarray(a) for a in _wake_case(case, rng)]
+        kern = functools.partial(wake_solve_kernel, h_cap=12, interpret=True)
+        oracle = functools.partial(wake_solve_ref, h_cap=12)
+        if case == "vmap_mixed_pops":
+            kern, oracle = jax.vmap(kern), jax.vmap(oracle)
+        wr, wk = np.asarray(oracle(*args)), np.asarray(kern(*args))
+        np.testing.assert_array_equal(wr, wk)
+        granted = wk <= 12
+        if case == "zero_pops":
+            assert not granted.any()
+        elif case == "deeper_than_cap":
+            assert granted.sum() == 12 * 3 < np.sum(np.asarray(args[0]) >= 0)
+        elif case == "credit_bound":
+            assert 0 < granted.sum() < 12 * 40
+        elif case == "vmap_mixed_pops":
+            assert not granted[0::2].any()
+            assert granted[1::2].any(axis=1).all()
+        return
     for trial, P in enumerate((128, 512 + 37)):
         for i in range(4):
             if i == 3:  # nothing queued: every page gets the sentinel

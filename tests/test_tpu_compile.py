@@ -5,8 +5,9 @@ that the chip's compiler refuses still passes there.  These tests compile
 ``batched_evict`` (f32 and i32 keys), ``fifo_grant`` and ``wake_solve``
 for a described ``v5e:2x2`` topology, with nothing attached, at the SF30
 TPC-H pool (P = 8,832 pages) and at a ragged pool (3 blocks + 129), the
-grant also at the benchmark cells' pool (P = 3,712) alone and under a
-four-lane ``jax.vmap``, and check that the compiled program holds the
+grant also at the micro cells' pool (P = 3,712) alone and under a
+four-lane ``jax.vmap``, the wake solve under that ``vmap`` at both cells'
+pools (P = 3,712 and 14,336), and check that the compiled program holds the
 Mosaic kernel under the name its ``pallas_call`` pins (``kernel_<op>``),
 the name the benchmark's trace reduction matches.
 
@@ -31,8 +32,10 @@ from repro.kernels.pbm_timeline import (
 #: the SF30 TPC-H pool (make_tpch_db(scale=1.0), 8 streams, seed 7) and a
 #: pool that leaves a ragged last page block
 POOLS = [8832, 3 * 512 + 129]
-#: the benchmark cells' pool (micro_lineitem_sf30: 3,635 pages, padded)
+#: the benchmark cells' pools (micro_lineitem_sf30: 3,635 pages, padded;
+#: tpch_sf30: 14,336 padded)
 CELL_POOL = 3712
+TPCH_CELL_POOL = 14336
 
 
 @pytest.fixture(scope="module")
@@ -116,15 +119,28 @@ def test_fifo_grant_compiles(one_chip, P, lanes):
         assert len(calls) == 1 and "kernel_fifo_grant" in calls[0]
 
 
-@pytest.mark.parametrize("P", POOLS)
-def test_wake_solve_compiles(one_chip, P):
+@pytest.mark.parametrize(
+    "P, lanes", [(P, None) for P in POOLS] + [(CELL_POOL, 4),
+                                              (TPCH_CELL_POOL, 4)],
+    ids=[str(P) for P in POOLS] + [f"4x{CELL_POOL}", f"4x{TPCH_CELL_POOL}"])
+def test_wake_solve_compiles(one_chip, P, lanes):
+    """The solve also compiles batched over four lanes, as the
+    simulator's ``jax.vmap`` calls it, at both benchmark pools: one
+    Mosaic kernel for all four lanes."""
+    batch = () if lanes is None else (lanes,)
+    fn = wake_solve_kernel if lanes is None else jax.vmap(wake_solve_kernel)
     text = _compile_text(
-        wake_solve_kernel,
-        _spec(one_chip, (P,), jnp.int32),
-        _spec(one_chip, (P,), jnp.float32),
-        _spec(one_chip, (), jnp.float32),
-        _spec(one_chip, (), jnp.float32),
-        _spec(one_chip, (), jnp.int32),
+        fn,
+        _spec(one_chip, batch + (P,), jnp.int32),
+        _spec(one_chip, batch + (P,), jnp.float32),
+        _spec(one_chip, batch, jnp.float32),
+        _spec(one_chip, batch, jnp.float32),
+        _spec(one_chip, batch, jnp.int32),
     )
     assert "tpu_custom_call" in text
-    assert "%kernel_wake_solve." in text
+    if lanes is None:
+        assert "%kernel_wake_solve." in text
+    else:
+        calls = [line for line in text.splitlines()
+                 if "custom_call_target=\"tpu_custom_call\"" in line]
+        assert len(calls) == 1 and "kernel_wake_solve" in calls[0]
